@@ -3,6 +3,8 @@
 Usage: python -m benchmarks._worker '<json spec>'
 spec = {"n_devices": int, "cases": [{integrand, d, rel_tol, capacity,
         classifier, redistribution, max_iters, use_kernel}]}
+Distributed cases run on the first ``n_devices`` visible devices (on CPU
+the worker forces that many virtual devices).
 Prints one line: RESULT_JSON:[...per-case records...]
 """
 
@@ -23,11 +25,16 @@ def main() -> None:
 
     jax.config.update("jax_enable_x64", True)
 
+    from repro import compile_cache
     from repro.core import integrands
     from repro.core.adaptive import integrate
     from repro.core.config import QuadratureConfig
     from repro.core.distributed import integrate_distributed
 
+    compile_cache.enable()
+    devices = jax.devices()[: max(n_dev, 1)]
+    if len(devices) < n_dev:
+        raise SystemExit(f"{n_dev} devices requested, {len(devices)} visible")
     out = []
     for case in spec["cases"]:
         case = dict(case)
@@ -38,12 +45,12 @@ def main() -> None:
             from repro.mc import integrate_vegas, integrate_vegas_distributed
 
             if distributed:
-                res = integrate_vegas_distributed(cfg)
+                res = integrate_vegas_distributed(cfg, devices=devices)
             else:
                 res = integrate_vegas(cfg)
             extra = {"chi2_dof": res.chi2_dof}
         elif distributed:
-            res = integrate_distributed(cfg)
+            res = integrate_distributed(cfg, devices=devices)
             extra = {
                 "mean_imbalance": res.mean_imbalance(),
                 "evals_per_device": res.evals_per_device.tolist(),

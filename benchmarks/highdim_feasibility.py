@@ -11,7 +11,8 @@ crossover the ``backend="auto"`` dimension threshold approximates.
 Cubature cases whose *initial evaluation* alone would exceed the memory
 guard are recorded as ``infeasible`` without being run (that is the point:
 at d = 15 one Genz-Malik sweep of the initial partition needs ~TBs), as are
-cases that crash or time out in the worker subprocess.
+cases that time out in the worker subprocess.  A worker that crashes is
+recorded as ``error``: a crash says nothing about feasibility.
 """
 
 import subprocess
@@ -45,14 +46,12 @@ def _run_case(case: dict, timeout: int) -> dict:
     try:
         (rec,) = run_worker({"n_devices": 1, "cases": [case]}, timeout=timeout)
         return rec
-    except (RuntimeError, subprocess.TimeoutExpired) as e:
-        return {
-            **case,
-            "status": "infeasible",
-            "rel_err": None,
-            "wall_s": None,
-            "detail": type(e).__name__,
-        }
+    except subprocess.TimeoutExpired:
+        status, detail = "infeasible", "timeout"
+    except RuntimeError as e:  # the worker exited non-zero
+        status, detail = "error", str(e)[-500:]
+    return {**case, "status": status, "rel_err": None, "wall_s": None,
+            "detail": detail}
 
 
 def run(fast: bool = True):

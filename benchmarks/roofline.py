@@ -42,7 +42,7 @@ import os
 # NOT this container's numbers: prefer a measured machine file
 # (``python -m repro.perf.machine``), resolved via :func:`resolve_terms`
 # below / the ``--machine`` flag.  The values are pinned to
-# ``repro.perf.machine.PRESETS["v5e"]`` by a drift test in
+# ``repro.perf.machine.PRESETS["TPU v5 lite"]`` by a drift test in
 # ``tests/test_perf.py``.
 PEAK_FLOPS = 197e12  # v5e bf16 peak per chip, FLOP/s
 HBM_BW = 819e9  # v5e HBM bandwidth per chip, B/s
@@ -53,17 +53,17 @@ _HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def resolve_terms(machine_path: str | None = None) -> tuple[float, float, float]:
-    """(peak_flops, mem_bw, ici_bw) from a machine file, else the v5e preset.
+    """(peak_flops, mem_bw, ici_bw) for the running device.
 
-    Resolution order matches ``repro.perf.machine.resolve_machine``: an
-    explicit path, then the committed ``results/perf/machine.json``, then
-    the v5e preset above.  A measured file with no inter-device probe
+    Resolved by ``repro.perf.machine.resolve_machine``: a machine file
+    measured on this device kind, else the preset for it.  A measured file
+    with no inter-device probe
     (single device) keeps the preset ICI term so the collective column
     stays defined.
     """
     from repro.perf.machine import resolve_machine
 
-    m = resolve_machine(machine_path, preset="v5e")
+    m = resolve_machine(machine_path)
     return (
         float(m["peak_flops"]),
         float(m["mem_bw"]),

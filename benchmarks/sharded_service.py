@@ -37,6 +37,7 @@ def _worker_main(spec: dict) -> None:
     jax.config.update("jax_enable_x64", True)
     import numpy as np
 
+    from repro import compile_cache
     from repro.core import QuadratureConfig
     from repro.core.integrands import get_param
     from repro.service import BatchScheduler, QuadRequest
@@ -58,7 +59,10 @@ def _worker_main(spec: dict) -> None:
     def fleet():
         return [QuadRequest(req_id=i, theta=t) for i, t in enumerate(thetas)]
 
+    compile_cache.enable()
     devices = jax.devices()[:n_dev]
+    if len(devices) < n_dev:
+        raise SystemExit(f"{n_dev} devices requested, {len(devices)} visible")
     out = {}
     for label in ("cold", "warm"):  # cold pays every window-rung compile once
         sched = BatchScheduler(cfg, family, devices=devices)
@@ -123,9 +127,11 @@ def run(fast: bool = True):
                 "bit_parity_vs_1dev": parity,
             }
         )
-        from benchmarks._common import save_results
+    # saved once, after every child has exited: collecting the device meta
+    # touches JAX, and this process must stay off the chip while they run
+    from benchmarks._common import save_results
 
-        save_results("sharded_service", out)  # incremental: keep partial runs
+    save_results("sharded_service", out)
     return out
 
 

@@ -1,19 +1,18 @@
 """Distributed adaptive quadrature with round-robin load redistribution.
 
-Re-executes itself with 8 forced host devices (the same code runs on a real
-multi-chip mesh unchanged), integrates a discontinuous integrand whose work
-concentrates on a few ranks, and prints the per-device balance with
+Integrates a discontinuous integrand whose work concentrates on a few ranks
+over every visible device, and prints the per-device balance with
 redistribution ON vs OFF.
 
-Run: PYTHONPATH=src python examples/distributed_quadrature.py
+Run on a multi-chip host:
+  PYTHONPATH=src python examples/distributed_quadrature.py
+On CPU, emulate a mesh first:
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python examples/distributed_quadrature.py
 """
 
-import os
-import subprocess
-import sys
 
-
-def main_worker() -> None:
+def main() -> None:
     import jax
 
     jax.config.update("jax_enable_x64", True)
@@ -23,11 +22,12 @@ def main_worker() -> None:
     from repro.core.distributed import integrate_distributed
     from repro.core.integrands import get
 
-    print(f"devices: {len(jax.devices())}")
+    devices = jax.devices()
+    print(f"devices: {len(devices)}")
     base = dict(d=4, integrand="f6", rel_tol=1e-6, capacity=1 << 13, max_iters=200)
     for redis in ("ring", "off"):
         cfg = QuadratureConfig(redistribution=redis, **base)
-        res = integrate_distributed(cfg)
+        res = integrate_distributed(cfg, devices=devices)
         exact = get("f6").exact(4)
         share = res.evals_per_device / max(res.n_evals, 1)
         print(
@@ -39,10 +39,4 @@ def main_worker() -> None:
 
 
 if __name__ == "__main__":
-    if os.environ.get("_REPRO_DIST_WORKER") == "1":
-        main_worker()
-    else:
-        env = dict(os.environ)
-        env["_REPRO_DIST_WORKER"] = "1"
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        sys.exit(subprocess.call([sys.executable, __file__], env=env))
+    main()

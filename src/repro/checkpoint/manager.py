@@ -54,9 +54,9 @@ class CheckpointManager:
     def save(self, step: int, tree, blocking: bool = False) -> None:
         self.wait()
         named, _ = _flatten(tree)
-        # snapshot to host now (cheap on CPU, device->host copy on TPU) so the
-        # training loop can keep mutating device buffers
-        host = [(name, np.asarray(leaf)) for name, leaf in named]
+        # copy to host now, so the caller may donate the device buffers to its
+        # next step right away (np.asarray would alias CPU device memory)
+        host = [(name, np.array(leaf)) for name, leaf in named]
         if blocking:
             self._write(step, host)
         else:

@@ -33,6 +33,7 @@ from repro.core import region_store
 from repro.core.classify import classify, error_budget, nonfinite_mask
 from repro.core.config import QuadratureConfig
 from repro.core.integrands import get as get_integrand
+from repro.core.programs import RungPrograms
 from repro.core.region_store import RegionState
 from repro.core.rules import make_rule
 from repro.core.split import classify_split_compact
@@ -132,9 +133,9 @@ def advance_ladder(cfg: QuadratureConfig) -> tuple[int, ...]:
     top rung always exactly ``capacity``: any rung covering the target is
     bit-identical, and the advance at a rung costs far less than the eval at
     the same rung, so fine granularity buys almost no runtime — while every
-    extra rung is one more traced-and-compiled branch in the ``lax.switch``
-    drivers (device-resident loop, vmapped batch engine), where compile time
-    is a real cost for short-lived engines.
+    extra rung is one more compiled program (or ``lax.switch`` branch in
+    the device-resident loop), where compile time is a real cost for
+    short-lived engines.
     """
     if not cfg.advance_window:
         return (cfg.capacity,)
@@ -155,19 +156,6 @@ def advance_target(n_active, capacity: int):
     return jnp.minimum(2 * n_active, capacity) if isinstance(
         n_active, jnp.ndarray
     ) else min(2 * int(n_active), capacity)
-
-
-def donate_argnums(platform: Optional[str] = None) -> tuple[int, ...]:
-    """Donate the state buffers of per-iteration dispatches.
-
-    The ``(C, d)`` SoA arrays are the dominant allocation; donating them lets
-    XLA update the population in place instead of copying it every step.
-    Skipped on CPU, where donation is unimplemented and only triggers a
-    warning per compiled executable.  ``platform`` is the platform of the
-    devices that will actually run the computation; default backend otherwise.
-    """
-    platform = platform or jax.default_backend()
-    return () if platform == "cpu" else (0,)
 
 
 def make_advance_step(
@@ -342,59 +330,56 @@ def integrate(
     """
     cfg, lo, hi, total_volume, rule, state = _setup(cfg, integrand)
 
-    donate = donate_argnums()
     ladder = eval_ladder(cfg)
     adv_ladder = advance_ladder(cfg)
     C = cfg.capacity
-    # One jitted variant per ladder rung, compiled on first use.  The host
-    # loop already syncs the active count each iteration, so the next window
-    # is known before dispatch and the switch costs nothing on device.  The
-    # advance (and the metric reductions) get the same treatment as the eval:
-    # a per-rung jit cache keyed by the windows the counts demand.
-    eval_cache: dict[int, Callable] = {}
-    metrics_cache: dict[int, Callable] = {}
-    adv_cache: dict[int, Callable] = {}
+    # One jitted variant per ladder rung, compiled on first use (the next
+    # rungs up compiled ahead, see repro.core.programs).  The host loop
+    # already syncs the active count each iteration, so the next window is
+    # known before dispatch and the switch costs nothing on device.  The
+    # advance (and the metric reductions) get the same treatment as the
+    # eval: a per-rung program keyed by the windows the counts demand.  Both
+    # donate the state: the (C, d) SoA arrays are the dominant allocation,
+    # and donation lets XLA update the population in place.
+    def build_eval(w: int) -> Callable:
+        return jax.jit(make_eval_step(cfg, rule, window=w), donate_argnums=0)
 
-    def eval_step_for(n_active: int) -> Callable[[RegionState], RegionState]:
-        w = region_store.select_window(ladder, n_active)
-        fn = eval_cache.get(w)
-        if fn is None:
-            fn = jax.jit(make_eval_step(cfg, rule, window=w), donate_argnums=donate)
-            eval_cache[w] = fn
-        return fn
-
-    def metrics_for(n_active: int) -> Callable:
+    def build_metrics(w: int) -> Callable:
         # any rung covering n_active reduces the same active mass bit-exactly
-        w = region_store.select_window(adv_ladder, n_active)
-        fn = metrics_cache.get(w)
-        if fn is None:
-            ww = None if w == C else w
+        ww = None if w == C else w
 
-            def metrics(state, _w=ww):
-                integral, error = state.global_estimates(window=_w)
-                act = state.active if _w is None else state.active[:_w]
-                return integral, error, jnp.sum(act)
+        def metrics(state):
+            integral, error = state.global_estimates(window=ww)
+            act = state.active if ww is None else state.active[:ww]
+            return integral, error, jnp.sum(act)
 
-            fn = jax.jit(metrics)
-            metrics_cache[w] = fn
-        return fn
+        return jax.jit(metrics)
 
-    def advance_for(n_active: int) -> Callable:
+    def build_advance(w: int) -> Callable:
+        ww = None if w == C else w
+        core = make_advance_step(cfg, total_volume, hi - lo, window=ww)
+
+        def advance_and_count(state):
+            state = core(state)
+            # post-split the population fits the advance window
+            act = state.active if ww is None else state.active[:ww]
+            return state, jnp.sum(act)
+
+        return jax.jit(advance_and_count, donate_argnums=0)
+
+    evals = RungPrograms(ladder, build_eval)
+    metrics = RungPrograms(adv_ladder, build_metrics)
+    advances = RungPrograms(adv_ladder, build_advance)
+
+    def eval_step_for(n_active: int, state) -> Callable:
+        return evals(region_store.select_window(ladder, n_active), state)
+
+    def metrics_for(n_active: int, state) -> Callable:
+        return metrics(region_store.select_window(adv_ladder, n_active), state)
+
+    def advance_for(n_active: int, state) -> Callable:
         w = region_store.select_window(adv_ladder, advance_target(n_active, C))
-        fn = adv_cache.get(w)
-        if fn is None:
-            ww = None if w == C else w
-            core = make_advance_step(cfg, total_volume, hi - lo, window=ww)
-
-            def advance_and_count(state, _core=core, _w=ww):
-                state = _core(state)
-                # post-split the population fits the advance window
-                act = state.active if _w is None else state.active[:_w]
-                return state, jnp.sum(act)
-
-            fn = jax.jit(advance_and_count, donate_argnums=donate)
-            adv_cache[w] = fn
-        return fn
+        return advances(w, state)
 
     converged = False
     nonfinite = False
@@ -402,9 +387,9 @@ def integrate(
     n_active = n_next = cfg.resolved_n_init()
     for _ in range(cfg.max_iters):
         with recorder.span("core.eval", window=int(n_next)):
-            state = eval_step_for(n_next)(state)
+            state = eval_step_for(n_next, state)(state)
             integral, error, n_active = (
-                float(x) for x in metrics_for(n_next)(state)
+                float(x) for x in metrics_for(n_next, state)(state)
             )
         if recorder.enabled:
             recorder.event(
@@ -431,8 +416,10 @@ def integrate(
         if n_active == 0:
             break
         with recorder.span("core.advance", n_active=int(n_active)):
-            state, n_dev = advance_for(int(n_active))(state)
+            state, n_dev = advance_for(int(n_active), state)(state)
             n_next = int(n_dev)
+    for programs in (evals, metrics, advances):
+        programs.close()
 
     return AdaptiveResult(
         integral=integral,

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,8 +38,10 @@ class QuadratureConfig:
     max_iters: int = 600
     classifier: str = "robust"  # "robust" (ours) | "aggressive" (PAGANI-like)
     rule: str = "genz_malik"  # "genz_malik" | "gauss_kronrod"
-    use_kernel: bool = False  # Pallas GM kernel (interpret on CPU) vs pure jnp
-    interpret: bool = True  # Pallas interpret mode (CPU validation); False on TPU
+    use_kernel: bool = False  # Pallas GM kernel vs pure jnp
+    # Pallas interpret mode; None = from the platform that runs the rule
+    # (interpreter on CPU, Mosaic on TPU; resolved in rules.make_rule)
+    interpret: Optional[bool] = None
     block_regions: int = 0  # kernel lanes per block; 0 = kernels.ops default
     dtype: str = "float64"
     # --- active-window evaluation --------------------------------------------

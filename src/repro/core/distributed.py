@@ -37,38 +37,18 @@ from repro.core.adaptive import (
     AdaptiveResult,
     advance_ladder,
     advance_target,
-    donate_argnums,
-    make_switched_estimates,
-    make_switched_eval_step,
+    eval_ladder,
+    make_eval_step,
 )
 from repro.core.classify import classify, error_budget
 from repro.core.config import QuadratureConfig
+from repro.core.programs import RungPrograms
 from repro.core.redistribution import balance_stats, make_schedule, redistribute
 from repro.core.region_store import RegionState
 from repro.core.rules import make_rule
 from repro.core.split import classify_split_compact
 
 AXIS = "dev"
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map with the replication checker disabled.
-
-    Loop carries built inside the body start device-invariant and become
-    device-varying after the first iteration; the static vma/rep checker
-    cannot express that, so it is disabled.  jax >= 0.5 exposes
-    ``jax.shard_map(check_vma=...)``; older releases only have
-    ``jax.experimental.shard_map.shard_map(check_rep=...)``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
 
 
 @dataclasses.dataclass
@@ -142,50 +122,35 @@ def _stacked_initial_state(cfg: QuadratureConfig, n_devices: int, dtype):
     )
 
 
-def make_switched_classify_split(
-    cfg: QuadratureConfig, total_volume: float, domain_width: np.ndarray
+def make_classify_split(
+    cfg: QuadratureConfig,
+    total_volume: float,
+    domain_width: np.ndarray,
+    window: Optional[int],
 ):
-    """Windowed classify + split/compact for the per-device fused step.
+    """Classify + split/compact at one advance window, for the fused step.
 
     Unlike :func:`repro.core.adaptive.make_advance_step` this takes the
     *psum'd* integral and global active count (every device classifies
     against the same equal-share threshold) and does NOT bump ``it`` — the
-    redistribution schedule indexes on the pre-bump counter.  The window rung
-    is picked per device from its LOCAL live count (the branches contain no
-    collectives, so devices may take different branches under SPMD).
+    redistribution schedule indexes on the pre-bump counter.
     """
     width = jnp.asarray(domain_width)
-    ladder = advance_ladder(cfg)
-    C = cfg.capacity
-
-    def branch(w: Optional[int]):
-        sl = slice(None) if w is None else slice(0, w)
-
-        def fn(state: RegionState, integral, n_global) -> RegionState:
-            fin = classify(
-                cfg,
-                state.est[sl],
-                state.err[sl],
-                state.halfw[sl],
-                state.active[sl],
-                integral,
-                total_volume,
-                width,
-                n_active=n_global,
-            )
-            return classify_split_compact(state, fin, window=w)
-
-        return fn
-
-    if len(ladder) == 1:
-        return branch(None)
-    branches = [branch(w) for w in ladder]
-    rungs = jnp.asarray(ladder, jnp.int32)
+    sl = slice(None) if window is None else slice(0, window)
 
     def apply(state: RegionState, integral, n_global) -> RegionState:
-        n = jnp.sum(state.active).astype(jnp.int32)
-        ix = region_store.rung_index(rungs, advance_target(n, C))
-        return jax.lax.switch(ix, branches, state, integral, n_global)
+        fin = classify(
+            cfg,
+            state.est[sl],
+            state.err[sl],
+            state.halfw[sl],
+            state.active[sl],
+            integral,
+            total_volume,
+            width,
+            n_active=n_global,
+        )
+        return classify_split_compact(state, fin, window=window)
 
     return apply
 
@@ -197,8 +162,9 @@ def make_dist_step(
     total_volume: float,
     domain_width: np.ndarray,
     schedule,
+    window: int,
 ):
-    """K-fused per-device step (K = ``cfg.sync_every``).
+    """K-fused per-device step (K = ``cfg.sync_every``) at one window rung.
 
     ``dist_step`` runs up to K full iterations inside one dispatch.  The
     convergence check runs on device against the psum'd metadata (which is
@@ -207,22 +173,38 @@ def make_dist_step(
     dispatch, reading back the stacked per-iteration metrics plus an
     ``executed`` mask — the paper's "overlap communication with computation"
     applied to the host<->device channel.
+
+    The step is compiled for ONE eval window (an eval-ladder rung) and its
+    advance window, the way the single-device host driver compiles one
+    program per rung: an iteration runs only while the widest device's live
+    count fits ``window`` (a pmax, so every rank agrees), and otherwise the
+    dispatch ends early and the host re-dispatches at the rung that count
+    needs.  Any covering window is bit-exact, so the trajectory is the one a
+    full-capacity step walks, and only the rungs a run reaches are compiled.
     """
-    eval_step = make_switched_eval_step(cfg, rule)
-    estimates = make_switched_estimates(cfg)
-    classify_split = make_switched_classify_split(cfg, total_volume, domain_width)
+    C = cfg.capacity
+    adv = region_store.select_window(advance_ladder(cfg), advance_target(window, C))
+    # None = the whole store
+    eval_w, adv_w = (None if window == C else window), (None if adv == C else adv)
+    eval_step = make_eval_step(cfg, rule, window=eval_w)
+    classify_split = make_classify_split(cfg, total_volume, domain_width, adv_w)
     limit = 3 * cfg.capacity // 4
     dtype = jnp.dtype(cfg.dtype)
 
+    def widest(state: RegionState):
+        return jax.lax.pmax(jnp.sum(state.active).astype(jnp.int32), AXIS)
+
     def dist_core(state: RegionState):
-        work_loc = jnp.sum(state.active & state.fresh)
+        # counts ride the collectives as int32: the TPU lowers only sums of
+        # 64-bit integers across devices, not their max
+        work_loc = jnp.sum(state.active & state.fresh).astype(jnp.int32)
         state = eval_step(state)
 
         # --- metadata exchange (the only global sync point) ----------------
-        i_loc, e_loc = estimates(state)
+        i_loc, e_loc = state.global_estimates(window=eval_w)
         integral = jax.lax.psum(i_loc, AXIS)
         error = jax.lax.psum(e_loc, AXIS)
-        n_loc = jnp.sum(state.active)
+        n_loc = jnp.sum(state.active).astype(jnp.int32)
         n_global = jax.lax.psum(n_loc, AXIS)
         work_max = jax.lax.pmax(work_loc, AXIS)
         work_sum = jax.lax.psum(work_loc, AXIS)
@@ -272,6 +254,8 @@ def make_dist_step(
 
         def one(carry, _):
             state, done = carry
+            # a count past the compiled window ends the dispatch unexecuted
+            done = done | (widest(state) > window)
             executed = ~done
 
             def run(s):
@@ -294,10 +278,47 @@ def make_dist_step(
         (state, _), (ms, executed) = jax.lax.scan(
             one, (state, jnp.asarray(False)), None, length=cfg.sync_every
         )
+        n_widest = widest(state)
         state = jax.tree.map(lambda x: x[None], state)
-        return state, ms, executed
+        return state, ms, executed, n_widest
 
     return dist_step
+
+
+def dist_program(
+    cfg: QuadratureConfig,
+    rule,
+    mesh: Mesh,
+    total_volume: float,
+    domain_width: np.ndarray,
+    window: int,
+):
+    """The jitted, donating SPMD program of :func:`make_dist_step` over
+    ``mesh`` at one eval rung: the program ``integrate_distributed``
+    dispatches while every device's live count fits ``window``."""
+    n_devices = mesh.shape[AXIS]
+    dist_step = make_dist_step(
+        cfg,
+        rule,
+        n_devices,
+        total_volume,
+        domain_width,
+        make_schedule(n_devices),
+        window,
+    )
+    # check_vma=False: loop carries built inside the body start
+    # device-invariant and become device-varying after the first iteration,
+    # which the static replication checker cannot express
+    return jax.jit(
+        jax.shard_map(
+            dist_step,
+            mesh=mesh,
+            in_specs=P(AXIS),
+            out_specs=(P(AXIS), P(), P(), P()),
+            check_vma=False,
+        ),
+        donate_argnums=0,
+    )
 
 
 def integrate_distributed(
@@ -307,7 +328,8 @@ def integrate_distributed(
     devices=None,
     recorder=None,
 ) -> DistributedResult:
-    """Host-driven multi-device integration over all available devices.
+    """Host-driven multi-device integration over ``devices`` (default: every
+    visible device; ``mesh`` overrides both).
 
     ``recorder`` (a :class:`repro.telemetry.Recorder`) gets a
     ``dist.dispatch`` span per fused launch and, per executed iteration, a
@@ -325,24 +347,17 @@ def integrate_distributed(
     hi = np.asarray(cfg.hi(), np.float64)
     total_volume = float(np.prod(hi - lo))
     dtype = jnp.dtype(cfg.dtype)
-    rule = make_rule(cfg, integrand)
-    schedule = make_schedule(n_devices)
+    rule = make_rule(cfg, integrand, platform=mesh.devices.flat[0].platform)
 
     state = _stacked_initial_state(cfg, n_devices, dtype)
+    n_widest = int(jnp.max(jnp.sum(state.active, axis=1)))
     shard = NamedSharding(mesh, P(AXIS))
     state = jax.device_put(state, shard)
 
-    dist_step = make_dist_step(
-        cfg, rule, n_devices, total_volume, hi - lo, schedule
-    )
-    step = jax.jit(
-        _shard_map(
-            dist_step,
-            mesh=mesh,
-            in_specs=P(AXIS),
-            out_specs=(P(AXIS), P(), P()),
-        ),
-        donate_argnums=donate_argnums(mesh.devices.flat[0].platform),
+    ladder = eval_ladder(cfg)
+    steps = RungPrograms(
+        ladder,
+        lambda w: dist_program(cfg, rule, mesh, total_volume, hi - lo, w),
     )
 
     from repro.telemetry import NULL
@@ -355,8 +370,10 @@ def integrate_distributed(
     it = 0
     while it < cfg.max_iters:
         with recorder.span("dist.dispatch", it=it) as sp:
-            state, ms, executed = step(state)
+            w = region_store.select_window(ladder, n_widest)
+            state, ms, executed, n_widest = steps(w, state)(state)
             executed = np.asarray(executed)
+            n_widest = int(n_widest)
             ms = jax.device_get(ms)
             sp["executed"] = int(np.sum(executed))
         for t in range(len(executed)):
@@ -393,6 +410,7 @@ def integrate_distributed(
             break
         if n_active == 0:
             break
+    steps.close()
 
     overflowed = bool(np.any(np.asarray(state.overflowed)))
     if converged:

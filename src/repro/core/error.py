@@ -13,7 +13,16 @@ extrapolate the estimate down by ``sqrt(2 r)``.
 Two gates keep the extrapolation honest (both regression-tested):
 
 - ratio gate ``r < 1/8``: at the gate boundary the shrink factor is at most
-  ``sqrt(2/8) = 1/2``;
+  ``sqrt(2/8) = 1/2``, and it never falls below ``1/4``: a tiny ``r`` can
+  also mean that the I5/I3 difference is large for a reason the degree-7
+  rule shares (an unresolved peak), and an unbounded shrink then claimed
+  up to 12x less than the true error on genz_gaussian at d=5
+  (regression-tested).  ``benchmarks/error_floor_sweep.py`` (270 runs:
+  f1-f7 at d=5 and 8, the three families at d=5-8, the smoke's 64-theta
+  fleet) sets the floor: unbounded, 5 runs claim convergence with a true
+  error over tolerance; at 1/4, only f4 at d=8, which no floor changes,
+  for 4.7% more evaluations; 1/2 costs more again (fewer runs converge
+  within capacity) and removes no further case;
 - smoothness gate: the per-axis fourth divided differences must be small
   relative to the mean integrand magnitude ``|I7|/vol``.  On boxes straddling
   a discontinuity (f6) or an unresolved oscillation (f1) the fourth
@@ -32,6 +41,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 _R_CRIT = 0.125
+_SHRINK_MIN = 0.25  # the extrapolation reduces n1 by at most 4x (see above)
 _SMOOTH_FRAC = 0.05  # fourth differences below 5% of mean |f| => smooth
 
 
@@ -58,7 +68,7 @@ def two_level_error(
     n2 = jnp.abs(i5 - i3)
 
     r = n1 / jnp.maximum(n2, tiny)
-    shrink = jnp.minimum(jnp.sqrt(2.0 * r), 1.0)
+    shrink = jnp.clip(jnp.sqrt(2.0 * r), _SHRINK_MIN, 1.0)
     f_mean = jnp.abs(i7) / jnp.maximum(vol, tiny)
     smooth = max_fourth_diff <= _SMOOTH_FRAC * f_mean
     asymptotic = (n2 > tiny) & (r < _R_CRIT) & smooth

@@ -108,6 +108,14 @@ def pair_generators(d: int) -> np.ndarray:
     return np.array(out, dtype=object)
 
 
+# The node groups below loop over their axes / axis pairs with fori_loop
+# rather than unrolling in Python: the program then holds a handful of
+# copies of the integrand (four per axis step, one per pair or corner node)
+# instead of one per node (4d + 4 d(d-1)/2 of them).  On a TPU every float64
+# op is emulated, so an unrolled rule made each window rung of the solver a
+# multi-ten-second compile.
+
+
 def _eval_axis_groups(f, centers, halfw, dtype):
     """Single-coordinate displacement sums + per-axis fourth differences.
 
@@ -116,45 +124,51 @@ def _eval_axis_groups(f, centers, halfw, dtype):
     """
     d = centers.shape[0]
     f0 = f(centers)
-    sum2 = jnp.zeros_like(f0)
-    sum3 = jnp.zeros_like(f0)
-    diffs = []
     rows = jnp.arange(d)[:, None]
-    for i in range(d):
+
+    def body(i, carry):
+        sum2, sum3, diffs = carry
         onehot = (rows == i).astype(dtype)
-        d2 = LAMBDA2 * halfw[i] * onehot
-        d3 = LAMBDA3 * halfw[i] * onehot
+        d2 = halfw * (LAMBDA2 * onehot)
+        d3 = halfw * (LAMBDA3 * onehot)
         f2p = f(centers + d2)
         f2m = f(centers - d2)
         f3p = f(centers + d3)
         f3m = f(centers - d3)
-        sum2 = sum2 + f2p + f2m
-        sum3 = sum3 + f3p + f3m
-        diffs.append(
-            jnp.abs(f2p + f2m - 2.0 * f0 - FOURTH_DIFF_RATIO * (f3p + f3m - 2.0 * f0))
+        diff = jnp.abs(
+            f2p + f2m - 2.0 * f0 - FOURTH_DIFF_RATIO * (f3p + f3m - 2.0 * f0)
         )
-    return sum2, sum3, f0, jnp.stack(diffs, axis=0)
+        return sum2 + f2p + f2m, sum3 + f3p + f3m, diffs.at[i].set(diff)
+
+    zeros = jnp.zeros_like(f0)
+    sum2, sum3, diffs = jax.lax.fori_loop(
+        0, d, body, (zeros, zeros, jnp.zeros((d,) + f0.shape, dtype))
+    )
+    return sum2, sum3, f0, diffs
 
 
 def _eval_pair_group(f, centers, halfw, dtype):
     """Group 3 sum: (+-lam4, +-lam4) over all axis pairs.  (B,)."""
     d = centers.shape[0]
     total = jnp.zeros(centers.shape[1], dtype=dtype)
+    pairs = [(i, j) for i in range(d) for j in range(i + 1, d)]
+    if not pairs:
+        return total
+    first = jnp.asarray([i for i, _ in pairs])
+    second = jnp.asarray([j for _, j in pairs])
+    # node k of the loop: pair k // 4 at (+,+), (+,-), (-,+), (-,-)
+    sign_i = jnp.asarray([1.0, 1.0, -1.0, -1.0], dtype) * LAMBDA4
+    sign_j = jnp.asarray([1.0, -1.0, 1.0, -1.0], dtype) * LAMBDA4
     rows = jnp.arange(d)[:, None]
-    for i in range(d):
-        for j in range(i + 1, d):
-            ei = (rows == i).astype(dtype)
-            ej = (rows == j).astype(dtype)
-            di = LAMBDA4 * halfw[i] * ei
-            dj = LAMBDA4 * halfw[j] * ej
-            total = (
-                total
-                + f(centers + di + dj)
-                + f(centers + di - dj)
-                + f(centers - di + dj)
-                + f(centers - di - dj)
-            )
-    return total
+
+    def body(k, total):
+        p, kind = k // 4, k % 4
+        step = sign_i[kind] * (rows == first[p]).astype(dtype) + sign_j[kind] * (
+            rows == second[p]
+        ).astype(dtype)
+        return total + f(centers + halfw * step)
+
+    return jax.lax.fori_loop(0, 4 * len(pairs), body, total)
 
 
 def _eval_corner_group(f, centers, halfw, dtype):

@@ -222,9 +222,9 @@ def window_ladder(capacity: int, min_window: int = 256) -> tuple[int, ...]:
 def rung_index(rungs: jnp.ndarray, n: jnp.ndarray) -> jnp.ndarray:
     """Index of the smallest ladder rung covering ``n`` (clamped to the top).
 
-    The device-side rung pick, shared by every ``lax.switch``-dispatched
-    windowed eval (single-device, distributed, batch service) so all paths
-    agree bit-for-bit with the host-side :func:`select_window`.
+    The device-side rung pick of the ``lax.switch``-dispatched windowed
+    stages (the device-resident driver), so they agree bit-for-bit with the
+    host-side :func:`select_window`.
     """
     return jnp.minimum(jnp.searchsorted(rungs, n), rungs.shape[0] - 1)
 

@@ -2,8 +2,9 @@
 
 from __future__ import annotations
 
-from typing import Callable, Protocol
+from typing import Callable, Optional, Protocol
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import gauss_kronrod, genz_malik
@@ -48,7 +49,7 @@ class GenzMalikRule:
         integrand: Callable[..., jnp.ndarray],
         noise_mult: float = 50.0,
         use_kernel: bool = False,
-        interpret: bool = True,
+        interpret: Optional[bool] = None,  # None: kernels.ops.resolve_interpret
         block_regions: int = 0,  # 0 = kernels.ops.DEFAULT_BLOCK_REGIONS
         theta=None,
     ):
@@ -121,8 +122,15 @@ class GaussKronrodRule:
         return i_k, err, axis
 
 
-def make_rule(cfg: QuadratureConfig, integrand=None, theta=None) -> Rule:
+def make_rule(
+    cfg: QuadratureConfig, integrand=None, theta=None, platform: Optional[str] = None
+) -> Rule:
     """Build the configured rule.
+
+    ``platform`` is that of the devices that run the rule (the default
+    backend when not given).  It resolves ``cfg.interpret=None`` once, here:
+    the Pallas interpreter on CPU, Mosaic elsewhere.  Mosaic has no float64,
+    so the kernel in float64 on a TPU is refused with the fix in the message.
 
     ``integrand`` overrides the config-named integrand with a plain callable
     ``f(x)``; passing ``theta`` as well marks it a ParamIntegrand family
@@ -145,12 +153,28 @@ def make_rule(cfg: QuadratureConfig, integrand=None, theta=None) -> Rule:
     else:
         f = get_integrand(cfg.integrand).fn
     if cfg.rule == "genz_malik":
+        interpret = cfg.interpret
+        if cfg.use_kernel:
+            from repro.kernels.ops import resolve_interpret
+
+            platform = platform or jax.default_backend()
+            interpret = resolve_interpret(interpret, platform)
+            if (
+                platform == "tpu"
+                and not interpret
+                and jnp.dtype(cfg.dtype) == jnp.float64
+            ):
+                raise ValueError(
+                    "use_kernel=True compiles the GM kernel through Mosaic, "
+                    "which has no float64: pass dtype=\"float32\" to keep "
+                    "the kernel, or use_kernel=False to stay in float64"
+                )
         return GenzMalikRule(
             cfg.d,
             f,
             noise_mult=cfg.noise_mult,
             use_kernel=cfg.use_kernel,
-            interpret=cfg.interpret,
+            interpret=interpret,
             block_regions=cfg.block_regions,
             theta=theta,
         )

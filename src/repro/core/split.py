@@ -39,6 +39,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.region_store import RegionState
@@ -52,9 +53,22 @@ def survivor_sort_perm(err: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
     descending error estimate, freed/inactive slots sink to the back (stable,
     so equal keys — and the inactive block — keep their relative order; the
     windowed and full-capacity sorts therefore agree on every live slot).
+
+    The key is the error rounded to float32 and mapped to an int32 of the
+    same order: XLA's TPU backend takes minutes to compile an argsort over
+    (emulated) float64 keys at each large window rung, and one int32 key
+    costs a small fraction of that.  Errors equal to float32 precision tie
+    (errors below float32's normal range tie with zero), and ties keep their
+    slot order.
     """
-    big = jnp.asarray(jnp.finfo(err.dtype).max, err.dtype)
-    return jnp.argsort(jnp.where(active, -err, big))
+    e32 = (-err).astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(e32, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)  # monotone
+    last = jnp.iinfo(jnp.int32).max
+    key = jnp.where(jnp.isnan(e32), last - 1, key)  # NaN after finite errors
+    key = jnp.where(active, key, last)
+    slots = jnp.arange(err.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((key, slots), num_keys=1, is_stable=True)[1]
 
 
 def _window(state: RegionState, window: Optional[int]) -> int:

@@ -12,8 +12,8 @@ TPU-native rethink (DESIGN.md §2):
   so no `(n_nodes, d)` coordinate matrix and no `(B, n_nodes)` value matrix
   ever exist in HBM — the kernel reads ``2 * d * BLOCK`` floats and writes
   ``(3 + d) * BLOCK`` floats, i.e. arithmetic intensity grows with
-  ``n_nodes(d) = O(2^d)``, putting the kernel firmly in the compute-bound
-  regime of the v5e roofline (see benchmarks/roofline.py);
+  ``n_nodes(d) = O(2^d)`` (where that puts it on a chip's roofline has not
+  been measured yet);
 - the O(2^d) full-sign group is a `fori_loop` with the sign pattern decoded
   from the loop counter's bits (no table in memory);
 - the degree-7, degree-5, degree-3 sums and the per-axis fourth differences
@@ -31,6 +31,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax._src.pallas.mosaic import lowering as mosaic_lowering
 from jax.experimental import pallas as pl
 
 from repro.core.genz_malik import (
@@ -40,6 +42,26 @@ from repro.core.genz_malik import (
     LAMBDA4,
     LAMBDA5,
     gm_weights,
+)
+
+
+def _prod_by_slices(x, *, axes, **_):
+    """``reduce_prod`` as a left fold of multiplies along each reduced axis."""
+    for ax in sorted(axes, reverse=True):
+        out = lax.index_in_dim(x, 0, ax, keepdims=False)
+        for i in range(1, x.shape[ax]):
+            out = out * lax.index_in_dim(x, i, ax, keepdims=False)
+        x = out
+    return x
+
+
+# Mosaic has no rule for reduce_prod, which the kernel's halfwidth product
+# and the product integrands (f2, genz_product_peak, monomial: jnp.prod over
+# the coordinate rows) trace to; this one multiplies the slices out.  Only
+# the Mosaic lowering changes: XLA paths, the interpreter included, keep
+# their own reduce.
+mosaic_lowering.register_lowering_rule(lax.reduce_prod_p)(
+    mosaic_lowering.lower_fun(_prod_by_slices, multiple_results=False)
 )
 
 
@@ -110,11 +132,15 @@ def _kernel(
 
     # --- full-sign corner group (lambda5): signs decoded from loop bits ------
     def corner_body(k, acc):
-        bits = jnp.stack([(k >> i) & 1 for i in range(d)]).astype(dtype)
-        signs = (1.0 - 2.0 * bits).reshape(d, 1)
+        bits = jnp.right_shift(k, rows) & 1  # (d, 1): bit i of k on row i
+        signs = (1 - 2 * bits).astype(dtype)
         return acc + feval(c + LAMBDA5 * h * signs)
 
-    sum5 = jax.lax.fori_loop(0, 2**d, corner_body, jnp.zeros_like(f0))
+    # int32 bounds: under x64 Python-int bounds make an int64 counter, which
+    # Mosaic cannot lower
+    sum5 = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(2**d), corner_body, jnp.zeros_like(f0)
+    )
 
     scale = jnp.prod(h, axis=0, keepdims=True)  # (1, B)
     i7_ref[...] = scale * (
@@ -135,13 +161,14 @@ def genz_malik_eval_soa(
     theta_rows: jnp.ndarray | None = None,  # (n_theta, C) broadcast operand
     *,
     block_regions: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Run the fused GM kernel over an SoA batch. Returns (i7, i5, i3, diffs).
 
     ``block_regions`` is required (the batch must already be padded to a
     block multiple): block sizing and padding live in ``kernels.ops``, the
-    single source of truth for the default.
+    single source of truth for the default.  So is ``interpret``: the
+    platform-derived default lives in ``kernels.ops.resolve_interpret``.
 
     ``theta_rows`` carries a ParamIntegrand family's flattened coefficients
     as an extra ``(n_theta, C)`` input (each row one scalar broadcast over
@@ -156,14 +183,19 @@ def genz_malik_eval_soa(
     dtype = centers.dtype
 
     kernel = functools.partial(_kernel, f=f, d=d, has_theta=theta_rows is not None)
-    row_spec = pl.BlockSpec((d, block_regions), lambda i: (0, i))
-    one_spec = pl.BlockSpec((1, block_regions), lambda i: (0, i))
+    # block index maps return int32 (a Python 0 is int64 under x64, which
+    # Mosaic cannot lower)
+    def col(i):
+        return jnp.int32(0), i
+
+    row_spec = pl.BlockSpec((d, block_regions), col)
+    one_spec = pl.BlockSpec((1, block_regions), col)
 
     in_specs = [row_spec, row_spec]
     operands = [centers, halfw]
     if theta_rows is not None:
         in_specs.append(
-            pl.BlockSpec((theta_rows.shape[0], block_regions), lambda i: (0, i))
+            pl.BlockSpec((theta_rows.shape[0], block_regions), col)
         )
         operands.append(theta_rows)
 

@@ -4,8 +4,8 @@
 :class:`repro.core.rules.GenzMalikRule` when ``use_kernel=True``.  It adapts
 the region store's AoS ``(B, d)`` layout to the kernel's SoA ``(d, B)``
 layout, pads the batch to the block size, and dispatches to the fused
-Pallas kernel (``interpret=True`` executes the kernel body on CPU — the
-validation mode for this container; on TPU pass ``interpret=False``).
+Pallas kernel.  ``interpret`` defaults to what the platform needs
+(:func:`resolve_interpret`): the Pallas interpreter on CPU, Mosaic on a TPU.
 
 ParamIntegrand families ride the same kernel with their coefficients as a
 proper operand: ``theta`` (a pytree of per-axis coefficient leaves) is
@@ -18,7 +18,7 @@ service passes theta as a traced, vmapped value).
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,21 @@ from repro.kernels.genz_malik_eval import genz_malik_eval_soa
 # This is the single source of truth for the block size: GenzMalikRule and
 # QuadratureConfig use 0 to mean "defer to this default".
 DEFAULT_BLOCK_REGIONS = 512
+
+
+def resolve_interpret(
+    interpret: Optional[bool], platform: Optional[str] = None
+) -> bool:
+    """``interpret=None`` means "interpret iff the rule runs on CPU".
+
+    ``platform`` is that of the devices that run the kernel (the default
+    backend when not given).  Pallas has no compiled CPU lowering, so the
+    interpreter is the only way to run the kernel there; anywhere else the
+    kernel is compiled.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    return (platform or jax.default_backend()) == "cpu"
 
 
 def block_and_pad(b: int, block_regions: int = 0) -> tuple[int, int]:
@@ -76,7 +91,7 @@ def genz_malik_eval(
     *,
     theta=None,  # optional ParamIntegrand theta pytree, leaves (leaf_len,)
     block_regions: int = 0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,  # None: resolve_interpret
 ):
     """Fused GM rule evaluation. Returns (i7, i5, i3, diffs[B, d]).
 
@@ -85,6 +100,7 @@ def genz_malik_eval(
     leaves enter the kernel as broadcast operand rows (see module docstring).
     """
     b, d = centers.shape
+    interpret = resolve_interpret(interpret)
     block, pad = block_and_pad(b, block_regions)
     ct = centers.T
     ht = halfw.T

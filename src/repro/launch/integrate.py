@@ -2,8 +2,9 @@
 
 Single device:
   PYTHONPATH=src python -m repro.launch.integrate --integrand f4 --d 5 --rel-tol 1e-7
-Distributed (one process, N local devices — same code on a real mesh):
-  PYTHONPATH=src python -m repro.launch.integrate --devices 8 --integrand f6 --d 5
+Distributed over the first N visible devices (on CPU, emulate them with
+XLA_FLAGS=--xla_force_host_platform_device_count=N):
+  PYTHONPATH=src python -m repro.launch.integrate --devices 4 --integrand f6 --d 5
 High dimension via the VEGAS Monte Carlo backend (see DESIGN.md §7); a bare
 family name samples a random theta from --theta-seed:
   PYTHONPATH=src python -m repro.launch.integrate --backend vegas --d 15 \
@@ -11,8 +12,21 @@ family name samples a random theta from --theta-seed:
 """
 
 import argparse
-import os
 import sys
+
+
+def select_devices(n: int) -> list:
+    """The first ``n`` visible devices; an error if fewer are visible."""
+    import jax
+
+    visible = jax.devices()
+    if n < 1 or n > len(visible):
+        raise SystemExit(
+            f"--devices {n} but {len(visible)} device(s) are visible (on CPU, "
+            f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} to "
+            "emulate a mesh)"
+        )
+    return visible[:n]
 
 
 def main() -> None:
@@ -27,8 +41,9 @@ def main() -> None:
     ap.add_argument(
         "--interpret",
         action=argparse.BooleanOptionalAction,
-        default=True,
-        help="Pallas interpret mode (keep on for CPU; --no-interpret on TPU)",
+        default=None,
+        help="Pallas interpret mode (default: from the platform — the "
+        "interpreter on CPU, Mosaic on a TPU)",
     )
     ap.add_argument(
         "--block-regions", type=int, default=0, help="kernel lanes per block (0 = default)"
@@ -74,7 +89,12 @@ def main() -> None:
         help="theta draw for a bare family-name --integrand (e.g. "
         "'genz_gaussian' without coefficients)",
     )
-    ap.add_argument("--devices", type=int, default=1)
+    ap.add_argument(
+        "--devices",
+        type=int,
+        default=1,
+        help="run distributed over the first N visible devices",
+    )
     ap.add_argument("--message-cap", type=int, default=512)
     ap.add_argument(
         "--redistribution",
@@ -115,20 +135,16 @@ def main() -> None:
     )
     args = ap.parse_args()
 
-    if args.devices > 1 and os.environ.get("_REPRO_INT_WORKER") != "1":
-        env = dict(os.environ)
-        env["_REPRO_INT_WORKER"] = "1"
-        env["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices} "
-            + env.get("XLA_FLAGS", "")
-        )
-        sys.exit(os.spawnvpe(os.P_WAIT, sys.executable, [sys.executable] + sys.argv, env))
-
     import jax
 
     jax.config.update("jax_enable_x64", True)
 
     import numpy as np
+
+    from repro import compile_cache
+
+    compile_cache.enable()
+    devices = select_devices(args.devices)
 
     from repro.core import QuadratureConfig, integrate, integrate_device
     from repro.core.distributed import integrate_distributed
@@ -190,14 +206,16 @@ def main() -> None:
         from repro.mc import integrate_vegas, integrate_vegas_distributed
 
         if args.devices > 1:
-            res = integrate_vegas_distributed(cfg, fn, recorder=recorder)
+            res = integrate_vegas_distributed(
+                cfg, fn, devices=devices, recorder=recorder
+            )
             print(res.summary())
             print(f"devices={args.devices} (sample shards split across mesh)")
         else:
             res = integrate_vegas(cfg, fn, recorder=recorder)
             print(res.summary())
     elif args.devices > 1:
-        res = integrate_distributed(cfg, fn, recorder=recorder)
+        res = integrate_distributed(cfg, fn, devices=devices, recorder=recorder)
         print(res.summary())
         print(f"devices={res.n_devices} mean_imbalance={res.mean_imbalance():.3f}")
     elif args.device_loop:

@@ -73,8 +73,9 @@ def main() -> None:
     ap.add_argument(
         "--interpret",
         action=argparse.BooleanOptionalAction,
-        default=True,
-        help="Pallas interpret mode (keep on for CPU; --no-interpret on TPU)",
+        default=None,
+        help="Pallas interpret mode (default: from the platform — the "
+        "interpreter on CPU, Mosaic on a TPU)",
     )
     ap.add_argument("--max-iters", type=int, default=300)
     ap.add_argument(
@@ -216,11 +217,13 @@ def main() -> None:
     jax.config.update("jax_enable_x64", True)
     import numpy as np
 
+    from repro import compile_cache
     from repro.core import QuadratureConfig
     from repro.core.integrands import get_param, parse_spec
     from repro.service import QuadRequest, serve
     from repro.service.batch_engine import estimate_state_bytes
 
+    compile_cache.enable()
     family = get_param(args.family)
     cfg = QuadratureConfig(
         d=args.d,

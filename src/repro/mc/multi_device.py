@@ -3,7 +3,7 @@
 MC is embarrassingly parallel, so the multi-device story is the clean
 counterpoint to the cubature backend's region migration: no load balancing,
 no payload exchange — each device evaluates ``mc_shards / n_devices`` of the
-iteration's fixed sample shards under the repo's ``_shard_map`` shim, the
+iteration's fixed sample shards under ``jax.shard_map``, the
 per-shard partial sums are all-gathered (device order == shard order) and
 combined in the engine's fixed left-to-right scan, and the grid/counts
 refinement runs replicated on every device from the identical combined
@@ -46,7 +46,6 @@ def integrate_vegas_distributed(
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.distributed import _shard_map
     from repro.mc.engine import (
         _resolve_serial_fn,
         drive,
@@ -71,7 +70,13 @@ def integrate_vegas_distributed(
     mesh = jax.make_mesh((n_dev,), (AXIS,), devices=devices)
     body = make_iterate(cfg, fn, axis_name=AXIS, n_devices=n_dev)
     iterate = jax.jit(
-        _shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()))
+        jax.shard_map(
+            body,
+            mesh=mesh,
+            in_specs=(P(),),
+            out_specs=(P(), P()),
+            check_vma=False,
+        )
     )
     return drive(cfg, iterate, callback, recorder=recorder)
 

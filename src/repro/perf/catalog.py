@@ -28,9 +28,9 @@ applied (recorded trip count 1).
 
 Timing calls the AOT-compiled executable directly (the lowered object from
 step 2), so the measured program is *exactly* the costed program — not a
-re-traced sibling.  The drivers donate state buffers on non-CPU platforms;
-the catalog therefore threads each call's output state back in as the next
-call's input, which keeps repeated timing valid under donation.
+re-traced sibling.  The drivers donate their state buffers; the catalog
+therefore threads each call's output state back in as the next call's
+input, which keeps repeated timing valid under donation.
 """
 
 from __future__ import annotations
@@ -56,10 +56,7 @@ KERNELS = ("gm_eval", "advance", "vegas_iterate", "service_dispatch")
 
 def _cost_of(compiled) -> Dict[str, float]:
     """FLOPs / bytes / buffer sizes of a compiled executable."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax <= 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
-    cost = cost or {}
+    cost = compiled.cost_analysis() or {}
     out = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -199,7 +196,7 @@ def gm_eval_entries(cfg, reps: int) -> List[Dict[str, Any]]:
     out = []
     for w in eval_ladder(cfg):
         state = _populated_region_state(cfg, n_active=w)
-        step = jax.jit(make_eval_step(cfg, rule, window=w))
+        step = jax.jit(make_eval_step(cfg, rule, window=w), donate_argnums=0)
         compiled = step.lower(state).compile()
         out.append(
             _entry(
@@ -240,7 +237,7 @@ def advance_entries(cfg, reps: int) -> List[Dict[str, Any]]:
         # est/err/axis must hold real rule output for classify to threshold
         state = jax.jit(make_eval_step(cfg, rule, window=w))(state)
         adv = make_advance_step(cfg, total_volume, hi - lo, window=w)
-        step = jax.jit(lambda s, _adv=adv: _adv(s))
+        step = jax.jit(lambda s, _adv=adv: _adv(s), donate_argnums=0)
         compiled = step.lower(state).compile()
         out.append(
             _entry(
@@ -288,6 +285,7 @@ def dispatch_entries(cfg, reps: int) -> List[Dict[str, Any]]:
     iterations (no early exit) — and the HLO scan-body counts are scaled by
     exactly that trip count (``scan_trips``, see module docstring).
     """
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -304,7 +302,9 @@ def dispatch_entries(cfg, reps: int) -> List[Dict[str, Any]]:
         jnp.asarray(cfg.sync_every, jnp.int32),
         jnp.asarray(0, jnp.int32),
     )
-    compiled = engine._run.lower(*args).compile()
+    # the program BatchEngine.run dispatches for this fleet's window rung
+    run = jax.jit(engine._make_run(engine._rung(state)), donate_argnums=0)
+    compiled = run.lower(*args).compile()
     return [
         _entry(
             "service_dispatch",
@@ -435,8 +435,8 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--machine",
         default=None,
-        help=f"machine file to predict from (default: {MACHINE_PATH} if "
-        "present, else the v5e preset)",
+        help=f"machine file to predict from (default: {MACHINE_PATH} if it "
+        "was measured on this device kind, else the preset for the device)",
     )
     ap.add_argument("--full", action="store_true", help="benchmark-scale shapes")
     ap.add_argument(

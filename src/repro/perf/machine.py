@@ -20,10 +20,10 @@ Every probe takes the best of ``reps`` timed repetitions — peak numbers
 answer "what can the hardware do", so interference should push estimates
 down, never up.
 
-:data:`PRESETS` carries documented vendor-sheet fallbacks for hardware we
-cannot measure from this container.  ``"v5e"`` is the exact constant set
-``benchmarks/roofline.py`` used to hardcode (197 TFLOP/s bf16, 819 GB/s
-HBM, 50 GB/s ICI per link); a drift test pins the two to each other.
+:data:`PRESETS` carries published vendor-sheet peaks, keyed by the
+``device_kind`` JAX reports.  A machine file is used only on a device of
+the kind it was measured on; a device with neither is an error, never a
+default (:func:`resolve_machine`).
 """
 
 from __future__ import annotations
@@ -41,15 +41,15 @@ _REPO = os.path.dirname(
 #: share one baseline; re-run ``python -m repro.perf.machine`` to refresh)
 DEFAULT_PATH = os.path.join(_REPO, "results", "perf", "machine.json")
 
-#: Vendor-sheet presets for devices this container cannot measure.  The
-#: ``"v5e"`` entry is the old hardcoded constant set of
-#: ``benchmarks/roofline.py`` (bf16 peak per chip, HBM bandwidth, ICI
-#: bandwidth per link) — kept bit-equal to those module constants by
-#: ``tests/test_perf.py`` so the documented fallback can never drift.
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.  TPU v5e
+#: (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 50 GB/s ICI per link.  ``benchmarks/roofline.py`` carries the
+#: same constants, pinned to this entry by ``tests/test_perf.py``.
 PRESETS: Dict[str, Dict[str, Any]] = {
-    "v5e": {
+    "TPU v5 lite": {
         "name": "v5e-preset",
         "source": "preset",
+        "reference": "Google Cloud documentation, TPU v5e",
         "peak_flops": 197e12,
         "mem_bw": 819e9,
         "reduce_bw": 819e9,
@@ -123,10 +123,6 @@ def _probe_ici(n: int, dtype, reps: int) -> Optional[Dict[str, float]]:
         return None
     from jax.sharding import PartitionSpec as P
 
-    try:  # jax-version-compat shim, mirrors repro.core.distributed
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except ImportError:  # pragma: no cover - newer jax
-        _shard_map = jax.shard_map
     mesh = jax.make_mesh((len(devs),), ("probe",), devices=devs)
     perm = [(i, (i + 1) % len(devs)) for i in range(len(devs))]
 
@@ -134,7 +130,7 @@ def _probe_ici(n: int, dtype, reps: int) -> Optional[Dict[str, float]]:
         return jax.lax.ppermute(x, "probe", perm)
 
     f = jax.jit(
-        _shard_map(rotate, mesh=mesh, in_specs=P("probe"), out_specs=P("probe"))
+        jax.shard_map(rotate, mesh=mesh, in_specs=P("probe"), out_specs=P("probe"))
     )
     x = jnp.ones((n * len(devs),), dtype)
     t = _best_time(lambda: f(x), reps)
@@ -237,18 +233,26 @@ def load_machine(path: str = DEFAULT_PATH) -> Optional[Dict[str, Any]]:
     return machine
 
 
-def resolve_machine(
-    path: Optional[str] = None, preset: str = "v5e"
-) -> Dict[str, Any]:
-    """The machine terms to predict with: measured file if present, else
-    the documented preset.
+def _device_kind() -> str:
+    """``device_kind`` of the device JAX computes on by default."""
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
+def resolve_machine(path: Optional[str] = None) -> Dict[str, Any]:
+    """The machine terms to predict with on the running device.
 
     This is the single resolution rule shared by the catalog, the report,
-    and ``benchmarks/roofline.py``: an explicit ``path`` must exist (a typo
-    silently falling back to v5e constants would poison every prediction);
-    with no path the committed default file is used when present and the
-    ``preset`` otherwise.
+    ``benchmarks/kernel_bench.py`` and ``benchmarks/roofline.py``.  A machine
+    file counts only on the device kind it was measured on (its
+    ``meta.device_kind``): an explicit ``path`` must exist and match; the
+    committed default file is used when it matches and skipped otherwise.
+    Without a matching file the :data:`PRESETS` entry for the running device
+    kind is used, and a device with neither is an error — peaks assumed for
+    another device would poison every prediction.
     """
+    kind = _device_kind()
     if path is not None:
         machine = load_machine(path)
         if machine is None:
@@ -256,11 +260,23 @@ def resolve_machine(
                 f"machine file {path} not found; generate one with "
                 "`python -m repro.perf.machine --out " + path + "`"
             )
+        measured_on = machine.get("meta", {}).get("device_kind")
+        if measured_on != kind:
+            raise ValueError(
+                f"machine file {path} was measured on {measured_on!r}, but "
+                f"this process runs on {kind!r}; measure this device with "
+                "`python -m repro.perf.machine --out <path>`"
+            )
         return machine
     machine = load_machine(DEFAULT_PATH)
-    if machine is not None:
+    if machine is not None and machine.get("meta", {}).get("device_kind") == kind:
         return machine
-    return dict(PRESETS[preset])
+    if kind in PRESETS:
+        return dict(PRESETS[kind])
+    raise ValueError(
+        f"no machine file and no preset for device kind {kind!r}; measure "
+        "it with `python -m repro.perf.machine`"
+    )
 
 
 def main(argv=None) -> int:

@@ -33,17 +33,18 @@ bit-identical results: every terminal ``QuadResult`` — converged, max_iters,
 or evicted — matches the single-device service exactly.
 
 Window discipline: each window must be a single static shape per dispatch,
-so each device picks the smallest ladder rung covering the widest live slot
-it owns (``lax.switch`` at the top level, each branch the vmapped op at one
-rung).  By the active-window invariance argument (any window >= n_active is
-exact for eval/reductions, any window >= min(2 * n_active, capacity) for the
+so the host picks the smallest ladder rung covering the widest live slot of
+the fleet and dispatches the program compiled for that rung (a dispatch
+that outgrows its rung stops early and the next one takes the wider rung).
+By the active-window invariance argument (any window >= n_active is exact
+for eval/reductions, any window >= min(2 * n_active, capacity) for the
 sort-based advance) every slot gets bit-identical estimates and trajectories
 to its own serial run at that rung — there is exactly one compiled
-executable per (d, rule, window-rung), shared across the whole batch.  The
-advance stage (classify + split/compact) and the global-estimate reductions
-are windowed the same way when ``cfg.advance_window`` is on, so the whole
-vmapped iteration scales with the widest live population, not store
-capacity.
+executable per (d, rule, window-rung), shared across the whole batch, and
+only the rungs a fleet reaches are compiled.  The advance stage (classify +
+split/compact) and the global-estimate reductions are windowed the same way
+when ``cfg.advance_window`` is on, so the whole vmapped iteration scales
+with the widest live population, not store capacity.
 """
 
 from __future__ import annotations
@@ -61,7 +62,6 @@ from repro.core import region_store
 from repro.core.adaptive import (
     advance_ladder,
     advance_target,
-    donate_argnums,
     eval_ladder,
     make_advance_step,
     make_eval_step,
@@ -69,8 +69,8 @@ from repro.core.adaptive import (
 )
 from repro.core.classify import nonfinite_mask
 from repro.core.config import QuadratureConfig
-from repro.core.distributed import _shard_map
 from repro.core.integrands import ParamIntegrand, get_param
+from repro.core.programs import RungPrograms
 from repro.core.redistribution import (
     dispatch_cyclic,
     exchange_pair_stats,
@@ -122,6 +122,13 @@ def _select_slots(mask: jnp.ndarray, new, old):
         return jnp.where(m, n, o)
 
     return jax.tree.map(sel, new, old)
+
+
+def _widest_live(state: "BatchState") -> jnp.ndarray:
+    """Largest active-region count over the live (occupied, not done) slots."""
+    live = state.occupied & ~state.done
+    counts = jnp.sum(state.regions.active, axis=1).astype(jnp.int32)
+    return jnp.max(jnp.where(live, counts, 0))
 
 
 def _ppermute_tree(tree, axis_name: str, perm):
@@ -225,26 +232,37 @@ class BatchEngine:
             family.sample_theta(cfg.d, np.random.default_rng(0)),
         )
 
-        platform = (
-            mesh.devices.flat[0].platform if mesh is not None else None
+        # the platform of the devices that run the rule resolves the
+        # kernel's interpret mode (rules.make_rule)
+        self._platform = (
+            mesh.devices.flat[0].platform
+            if mesh is not None
+            else (devices[0].platform if devices else None)
         )
-        donate = donate_argnums(platform)
-        # build span only: the jits trace/compile lazily on first dispatch,
-        # which the scheduler's "service.compile" span captures instead
+        # every jitted op donates the fleet state (argument 0).  Build span
+        # only: the jits trace/compile lazily on first dispatch, which the
+        # scheduler's "service.compile" span captures instead
         with recorder.span(
             "engine.build",
             backend=self.backend,
             slots=self.n_slots,
             devices=self.n_devices,
         ):
-            self._iter = self._make_iter()
-            self._step = jax.jit(self._make_step(), donate_argnums=donate)
-            self._run = jax.jit(self._make_run(), donate_argnums=donate)
-            self._admit = jax.jit(
-                self._sharded(self._make_admit()), donate_argnums=donate
+            # one step and one fused run per eval-window rung, compiled on
+            # first use (see _rung)
+            self._ladder = eval_ladder(cfg)
+            self._steps = RungPrograms(
+                self._ladder,
+                lambda w: jax.jit(self._make_step(w), donate_argnums=0),
             )
+            self._runs = RungPrograms(
+                self._ladder,
+                lambda w: jax.jit(self._make_run(w), donate_argnums=0),
+            )
+            self._widest = jax.jit(_widest_live)
+            self._admit = jax.jit(self._sharded(self._make_admit()), donate_argnums=0)
             self._release = jax.jit(
-                self._sharded(self._make_release()), donate_argnums=donate
+                self._sharded(self._make_release()), donate_argnums=0
             )
 
     @staticmethod
@@ -285,11 +303,12 @@ class BatchEngine:
             return fn
 
         def wrapper(state, *args):
-            return _shard_map(
+            return jax.shard_map(
                 fn,
                 mesh=self.mesh,
                 in_specs=(P(AXIS),) + (P(),) * len(args),
                 out_specs=P(AXIS),
+                check_vma=False,
             )(state, *args)
 
         return wrapper
@@ -418,8 +437,9 @@ class BatchEngine:
 
     # --- the batched adaptive step -------------------------------------------
 
-    def _make_iter(self):
-        """One adaptive iteration over whatever slot block the caller holds.
+    def _make_iter(self, window: int):
+        """One adaptive iteration over whatever slot block the caller holds,
+        at eval window ``window`` (a rung covering every live slot).
 
         Shape-polymorphic in the leading slot axis: the single-device step
         applies it to all ``batch_slots`` slots, the sharded fused run to each
@@ -431,57 +451,41 @@ class BatchEngine:
         family = self.family
         total_volume = self._total_volume
         C = cfg.capacity
-        ladder = eval_ladder(cfg)
-        rungs = jnp.asarray(ladder, jnp.int32)
-        adv_ladder = advance_ladder(cfg)
-        adv_rungs = jnp.asarray(adv_ladder, jnp.int32)
 
-        def eval_branch(window: int):
-            def eval_one(regions: RegionState, theta) -> RegionState:
-                # theta rides as a rule operand (not a closure) so the Pallas
-                # kernel path works under vmap — see rules.make_rule
-                rule = make_rule(cfg, family.fn, theta=theta)
-                return make_eval_step(cfg, rule, window=window)(regions)
+        def eval_one(regions: RegionState, theta) -> RegionState:
+            # theta rides as a rule operand (not a closure) so the Pallas
+            # kernel path works under vmap — see rules.make_rule
+            rule = make_rule(cfg, family.fn, theta=theta, platform=self._platform)
+            return make_eval_step(cfg, rule, window=window)(regions)
 
-            return jax.vmap(eval_one)
+        evaluate = jax.vmap(eval_one)
 
-        branches = [eval_branch(w) for w in ladder]
+        # The post-eval tail of the iteration at the advance rung covering
+        # min(2 * window, C): the global-estimate reductions, the per-slot
+        # budget, and the serial drivers' advance (vmapped with per-slot
+        # traced tolerances).  Any wider window is bit-identical for the
+        # narrower slots, so one shared rung is exact.
+        adv_window = region_store.select_window(
+            advance_ladder(cfg), advance_target(window, C)
+        )
+        adv = jax.vmap(
+            make_advance_step(cfg, total_volume, self._width, window=adv_window)
+        )
 
-        # One windowed branch per advance rung, carrying the whole
-        # post-eval tail of the iteration: the global-estimate reductions,
-        # the per-slot budget, and the serial drivers' advance (vmapped with
-        # per-slot traced tolerances).  The rung covers
-        # min(2 * n_active, C) for the widest live slot — any wider window
-        # is bit-identical for the narrower slots, so one shared rung is
-        # exact, and folding the reductions into the same switch keeps the
-        # traced program (and its compile time) proportional to the ladder.
-        def tail_branch(window: int):
-            adv = jax.vmap(
-                make_advance_step(cfg, total_volume, self._width, window=window)
-            )
+        def est_one(regions: RegionState):
+            integral, error = regions.global_estimates(window=adv_window)
+            n = jnp.sum(regions.active[:adv_window]).astype(jnp.int32)
+            return integral, error, n
 
-            def est_one(regions: RegionState):
-                integral, error = regions.global_estimates(window=window)
-                n = jnp.sum(regions.active[:window]).astype(jnp.int32)
-                return integral, error, n
-
-            def fn(regions: RegionState, abs_tol, rel_tol):
-                integral, error, n_active = jax.vmap(est_one)(regions)
-                budget = jnp.maximum(abs_tol, jnp.abs(integral) * rel_tol)
-                advanced = adv(regions, budget, rel_tol)
-                return integral, error, n_active, budget, advanced
-
-            return fn
-
-        tail_branches = [tail_branch(w) for w in adv_ladder]
+        def tail(regions: RegionState, abs_tol, rel_tol):
+            integral, error, n_active = jax.vmap(est_one)(regions)
+            budget = jnp.maximum(abs_tol, jnp.abs(integral) * rel_tol)
+            advanced = adv(regions, budget, rel_tol)
+            return integral, error, n_active, budget, advanced
 
         def iter_fn(state: BatchState):
             live = state.occupied & ~state.done
-            counts = jnp.sum(state.regions.active, axis=1).astype(jnp.int32)
-            widest = jnp.max(jnp.where(live, counts, 0))
-            ix = region_store.rung_index(rungs, widest)
-
-            evald = jax.lax.switch(ix, branches, state.regions, state.theta)
+            evald = evaluate(state.regions, state.theta)
             regions = _select_slots(live, evald, state.regions)
 
             # --- non-finite quarantine ---------------------------------------
@@ -513,15 +517,9 @@ class BatchEngine:
                 fin_error=jnp.where(bad_fin, 0.0, regions.fin_error),
             )
 
-            if len(adv_ladder) > 1:
-                ixa = region_store.rung_index(adv_rungs, advance_target(widest, C))
-                integral, error, n_active, budget, advanced = jax.lax.switch(
-                    ixa, tail_branches, regions, state.abs_tol, state.rel_tol
-                )
-            else:
-                integral, error, n_active, budget, advanced = tail_branches[0](
-                    regions, state.abs_tol, state.rel_tol
-                )
+            integral, error, n_active, budget, advanced = tail(
+                regions, state.abs_tol, state.rel_tol
+            )
             converged = error <= budget
             # Capacity pressure is not instantly terminal: the serial driver
             # grinds past overflow and often converges, so an overflowed slot
@@ -566,7 +564,7 @@ class BatchEngine:
                 "nonfinite": nonfinite,
                 "done": done,
                 "occupied": state.occupied,
-                "window": rungs[ix],
+                "window": jnp.asarray(window, jnp.int32),
             }
             return (
                 dataclasses.replace(
@@ -578,8 +576,8 @@ class BatchEngine:
 
         return iter_fn
 
-    def _make_step(self):
-        iter_fn = self._iter
+    def _make_step(self, window: int):
+        iter_fn = self._make_iter(window)
 
         def step(state: BatchState):
             state, metrics, _ = iter_fn(state)
@@ -597,7 +595,17 @@ class BatchEngine:
         scheduler collects and releases them.  (On a sharded engine this is
         the GSPMD form; the scheduler drives :meth:`run` instead.)
         """
-        return self._step(state)
+        return self._steps(self._rung(state), state)(state)
+
+    def _rung(self, state: BatchState) -> int:
+        """The eval-ladder rung covering the widest live slot of ``state``.
+
+        Each step and fused run is compiled for one rung, as the serial host
+        driver compiles one eval per rung: only the rungs a fleet reaches
+        are ever compiled, where one program switching over the whole ladder
+        compiled every rung up front.
+        """
+        return region_store.select_window(self._ladder, int(self._widest(state)))
 
     # --- problem-level cyclic rebalancing ------------------------------------
 
@@ -702,19 +710,22 @@ class BatchEngine:
 
     # --- the fused multi-iteration dispatch -----------------------------------
 
-    def _make_run(self):
-        """Build the K-fused dispatch (K = ``cfg.sync_every``).
+    def _make_run(self, window: int):
+        """Build the K-fused dispatch (K = ``cfg.sync_every``) at one rung.
 
         Runs up to ``max_steps`` iterations in one XLA dispatch and stops
         early — remaining iterations become pass-throughs — as soon as any
         live slot's ``done`` flips on (decided from a psum of per-slot done
         masks, the fleet's single global sync point), so the host scheduler
         observes every collection at its exact iteration and can replay
-        admission/eviction decisions identically to an unfused loop.
+        admission/eviction decisions identically to an unfused loop.  It also
+        stops before an iteration whose widest live slot (across the mesh)
+        has outgrown ``window``: the host then dispatches again at the rung
+        that slot needs.
         """
         cfg = self.cfg
         n_dev = self.n_devices
-        iter_fn = self._iter
+        iter_fn = self._make_iter(window)
         rebalance_on = n_dev > 1 and cfg.rebalance != "off"
         rebalance = self._make_rebalance_round() if rebalance_on else None
         moved_rows = self.rebalance_cap if n_dev > 1 else 0
@@ -743,7 +754,10 @@ class BatchEngine:
         def run_body(state: BatchState, max_steps, tick):
             def one(carry, t):
                 state, stop = carry
-                go = (~stop) & (t < max_steps)
+                widest = _widest_live(state)
+                if n_dev > 1:
+                    widest = jax.lax.pmax(widest, AXIS)
+                go = (~stop) & (t < max_steps) & (widest <= window)
 
                 def do(state):
                     state, metrics, n_new = iter_fn(state)
@@ -772,11 +786,14 @@ class BatchEngine:
 
         if self.mesh is None:
             return run_body
-        return _shard_map(
+        # check_vma=False: scan carries start device-invariant and become
+        # device-varying, which the static replication checker cannot express
+        return jax.shard_map(
             run_body,
             mesh=self.mesh,
             in_specs=(P(AXIS), P(), P()),
             out_specs=(P(AXIS), P(None, AXIS), P(), P(None, AXIS, None)),
+            check_vma=False,
         )
 
     backend = "cubature"
@@ -815,8 +832,9 @@ class BatchEngine:
         ``tick`` is the fleet-global iteration number of the first fused
         iteration (indexes the cyclic migration schedule).
         """
-        return self._run(
+        args = (
             state,
             jnp.asarray(min(int(max_steps), self.cfg.sync_every), jnp.int32),
             jnp.asarray(tick, jnp.int32),
         )
+        return self._runs(self._rung(state), *args)(*args)

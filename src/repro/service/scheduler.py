@@ -97,28 +97,62 @@ class DispatchTimeout(RuntimeError):
     Unlike :class:`DeviceLostError` it carries no device attribution — a
     hang looks the same from the host regardless of which device wedged —
     so the scheduler falls back to the injector's ``healthy`` probe (or
-    gives up) to pick the device to declare failed.
+    gives up) to pick the device to declare failed.  ``retryable`` is False
+    when the wedged launch had already taken (donated) the fleet state.
     """
+
+    def __init__(self, message: str, retryable: bool = True):
+        super().__init__(message)
+        self.retryable = retryable
+
+
+class _Attempt:
+    """One watched dispatch attempt, shared by the watchdog and its worker.
+
+    The fused dispatch donates the fleet state, so once a launch has taken
+    the state there is nothing left to retry or evacuate with.  The worker
+    may take the state only while the watchdog has not given up on it
+    (:meth:`take`), and a watchdog that gives up learns whether it already
+    had (:meth:`abandon`) — check and act under one lock, so an abandoned
+    attempt that wakes up later can never consume the state of its retry.
+    """
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._abandoned = False
+        self._taken = False
+
+    def take(self) -> bool:
+        with self._lock:
+            if not self._abandoned:
+                self._taken = True
+            return self._taken
+
+    def abandon(self) -> bool:
+        with self._lock:
+            self._abandoned = True
+            return self._taken
 
 
 def _call_with_timeout(fn: Callable, timeout_s: Optional[float]):
-    """Run ``fn()`` under a wall-clock watchdog.
+    """Run ``fn(attempt)`` under a wall-clock watchdog.
 
     With a timeout the call runs on a daemon thread and a ``join`` bounds
     the wait: a wedged dispatch raises :class:`DispatchTimeout` on the host
-    and the stuck thread is abandoned.  Exceptions from ``fn`` itself
-    propagate unchanged either way.  Retrying after a timeout presumes the
-    abandoned attempt never consumed the state buffers — true of the
-    deterministic injectors, which stall in the pre-dispatch hook before
-    the engine touches the state.
+    and the stuck thread is abandoned.  ``fn`` must call ``attempt.take()``
+    before it consumes the state and skip the dispatch when that returns
+    False.  A timeout after the state was taken is not retryable
+    (``DispatchTimeout.retryable`` is False): the wedged launch owns the
+    donated buffers.  Exceptions from ``fn`` itself propagate unchanged.
     """
+    attempt = _Attempt()
     if timeout_s is None:
-        return fn()
+        return fn(attempt)
     box: dict = {}
 
     def target():
         try:
-            box["value"] = fn()
+            box["value"] = fn(attempt)
         except BaseException as err:  # noqa: BLE001 - re-raised on the host
             box["error"] = err
 
@@ -126,8 +160,12 @@ def _call_with_timeout(fn: Callable, timeout_s: Optional[float]):
     worker.start()
     worker.join(timeout_s)
     if worker.is_alive():
+        taken = attempt.abandon()
         raise DispatchTimeout(
             f"fused dispatch still running after {timeout_s}s watchdog"
+            + ("; it had already taken the fleet state, so it cannot be "
+               "retried (resume from the service checkpoint)" if taken else ""),
+            retryable=not taken,
         )
     if "error" in box:
         raise box["error"]
@@ -678,12 +716,13 @@ class BatchScheduler:
             target = self._healthy_mesh()
             new_per = B // len(target)
             with rec.span("service.evacuate", it=it, device=dev) as sp:
-                # Host copy of the fleet state.  The fault fired at the
-                # dispatch boundary (pre-dispatch hook / abandoned launch),
-                # so the buffers were never donated into a completed
-                # dispatch and remain readable.  A real device loss would
-                # lose the failed shard's rows — exactly the rows rewritten
-                # or released below; surviving rows are all that is trusted.
+                # Host copy of the fleet state.  The fault fired before any
+                # launch took the state (pre-dispatch hook, or a watchdog
+                # timeout that _Attempt caught before the dispatch), so the
+                # buffers were never donated and remain readable.  A real
+                # device loss would lose the failed shard's rows — exactly
+                # the rows rewritten or released below; surviving rows are
+                # all that is trusted.
                 host = jax.tree.map(np.array, jax.device_get(state))
                 snap_state = snap_meta = None
                 if self.checkpointer is not None:
@@ -783,12 +822,14 @@ class BatchScheduler:
                 max_steps = min(max_steps, cfg.admit_every - it % cfg.admit_every)
             it0 = it
 
-            def attempt_dispatch():
+            def attempt_dispatch(attempt):
                 # the injector hook fires first: an injected loss surfaces
                 # before the engine consumes (donates) the state buffers,
                 # so a retry or an evacuation reads intact state
                 if self.fault_injector is not None:
                     self.fault_injector.pre_dispatch(it, tuple(self._current_devs))
+                if not attempt.take():
+                    return None  # the watchdog gave up on this attempt
                 new_state, ms, executed, moved = engine.run(state, max_steps, it)
                 ms, executed, moved = jax.device_get((ms, executed, moved))
                 return new_state, ms, executed, moved
@@ -818,6 +859,8 @@ class BatchScheduler:
                         k = int(np.sum(executed))
                         break
                     except (DeviceLostError, DispatchTimeout) as err:
+                        if isinstance(err, DispatchTimeout) and not err.retryable:
+                            raise  # the state went with the wedged launch
                         dev = self._attribute_fault(err, it)
                         rec.event(
                             "service.dispatch_fault",

@@ -462,3 +462,45 @@ def test_poison_theta_only_touches_first_leaf():
     assert np.all(np.asarray(bad_leaves[0]) == NAN_SENTINEL)
     for a, b in zip(leaves[1:], bad_leaves[1:]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_watchdog_abandoned_attempt_never_takes_the_state():
+    """A hung attempt that wakes after the watchdog gave up must not consume
+    (donate) the fleet state its retry is using."""
+    import threading
+
+    from repro.service.scheduler import DispatchTimeout, _call_with_timeout
+
+    wake, done = threading.Event(), threading.Event()
+    took = []
+
+    def hung(attempt):
+        wake.wait(10)
+        took.append(attempt.take())
+        done.set()
+
+    with pytest.raises(DispatchTimeout) as exc:
+        _call_with_timeout(hung, 0.05)
+    assert exc.value.retryable
+    wake.set()
+    assert done.wait(10)
+    assert took == [False]
+
+
+def test_watchdog_timeout_after_the_state_was_taken_is_not_retried():
+    import threading
+
+    from repro.service.scheduler import DispatchTimeout, _call_with_timeout
+
+    release = threading.Event()
+
+    def wedged(attempt):
+        assert attempt.take()
+        release.wait(10)
+
+    try:
+        with pytest.raises(DispatchTimeout, match="cannot be retried") as exc:
+            _call_with_timeout(wedged, 0.05)
+        assert not exc.value.retryable
+    finally:
+        release.set()

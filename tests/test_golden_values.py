@@ -62,3 +62,20 @@ def test_exact_values_are_finite_and_stable():
             b = family.exact(d, theta)
             assert a == b, name
             assert np.isfinite(a) and a != 0.0, (name, d, a)
+
+
+def test_claimed_error_bounds_true_error_on_a_peaked_gaussian():
+    """A genz_gaussian whose degree-5/3 difference dwarfs the degree-7/5 one
+    while its peak is unresolved: an unbounded extrapolation shrink claimed
+    convergence at 1e-6 with a true error of 1.0e-5."""
+    family = get_param("genz_gaussian")
+    rng = np.random.default_rng(0)
+    theta = [family.sample_theta(5, rng) for _ in range(9)][8]
+    bound = bind(family, theta)
+    cfg = QuadratureConfig(
+        d=5, integrand="genz_gaussian", rel_tol=1e-6, capacity=1 << 16
+    )
+    res = integrate(cfg, bound.fn)
+    exact = bound.exact(5)
+    assert res.status == "converged", res.summary()
+    assert abs(res.integral - exact) <= res.error, (res.summary(), exact)

@@ -187,3 +187,17 @@ def test_batch_engine_kernel_path_matches_serial(name):
         assert r.integral == serial.integral
         assert r.error == serial.error
         assert r.n_evals == serial.n_evals
+
+
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False)])
+def test_interpret_resolves_from_platform(platform, want):
+    """interpret=None means "interpret iff the rule runs on CPU", resolved
+    once in make_rule; an explicit setting wins."""
+    from repro.core.config import QuadratureConfig
+    from repro.core.rules import make_rule
+
+    assert ops.resolve_interpret(None, platform) is want
+    assert ops.resolve_interpret(not want, platform) is (not want)
+    cfg = QuadratureConfig(d=2, use_kernel=True, dtype="float32")
+    assert cfg.interpret is None
+    assert make_rule(cfg, platform=platform).interpret is want

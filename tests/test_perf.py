@@ -70,6 +70,27 @@ def test_resolve_machine_explicit_path_must_exist(tmp_path):
         resolve_machine(str(tmp_path / "nope.json"))
 
 
+def test_resolve_machine_refuses_file_from_another_device(tmp_path, monkeypatch):
+    from repro.perf import machine as machine_lib
+
+    path = str(tmp_path / "machine.json")
+    save_machine({"peak_flops": 1.0, "mem_bw": 1.0, "meta": {"device_kind": "cpu"}}, path)
+    assert machine_lib.resolve_machine(path)["peak_flops"] == 1.0  # tests run on cpu
+    monkeypatch.setattr(machine_lib, "_device_kind", lambda: "TPU v5 lite")
+    with pytest.raises(ValueError, match="measured on 'cpu'"):
+        machine_lib.resolve_machine(path)
+    # the committed default file (a CPU run) is skipped for the preset
+    assert machine_lib.resolve_machine() == PRESETS["TPU v5 lite"]
+
+
+def test_resolve_machine_refuses_unknown_device(monkeypatch):
+    from repro.perf import machine as machine_lib
+
+    monkeypatch.setattr(machine_lib, "_device_kind", lambda: "TPU v99")
+    with pytest.raises(ValueError, match="no preset for device kind 'TPU v99'"):
+        machine_lib.resolve_machine()
+
+
 def test_v5e_preset_pinned_to_roofline_constants():
     """The documented fallback can never drift from the retired constants."""
     sys.path.insert(0, _REPO)
@@ -77,7 +98,7 @@ def test_v5e_preset_pinned_to_roofline_constants():
         from benchmarks import roofline
     finally:
         sys.path.pop(0)
-    v5e = PRESETS["v5e"]
+    v5e = PRESETS["TPU v5 lite"]
     assert v5e["peak_flops"] == roofline.PEAK_FLOPS
     assert v5e["mem_bw"] == roofline.HBM_BW
     assert v5e["ici_bw"] == roofline.ICI_BW

@@ -83,6 +83,26 @@ def test_split_children_partition_parent(seed, axis):
     assert np.isclose(ca[axis] + h_child[axis], cb[axis] - h_child[axis])
 
 
+@given(seed=st.integers(0, 2**31 - 1), n=st.integers(1, 300))
+@settings(**_SETTINGS)
+def test_survivor_sort_is_a_stable_descending_error_order(seed, n):
+    """Active slots first by non-increasing float32-rounded error, ties and
+    the inactive tail in slot order — a stable argsort of that key (errors
+    below float32's normal range count as zero: the device flushes them)."""
+    from repro.core.split import survivor_sort_perm
+
+    rng = np.random.default_rng(seed)
+    err = np.abs(rng.standard_normal(n)) * 10.0 ** rng.integers(-40, 10, n)
+    err[rng.random(n) < 0.2] = 0.0
+    err[rng.random(n) < 0.2] = err[0]  # exact ties
+    active = rng.random(n) < 0.7
+    perm = np.asarray(survivor_sort_perm(jnp.asarray(err), jnp.asarray(active)))
+    e32 = err.astype(np.float32)
+    e32[e32 < np.finfo(np.float32).tiny] = 0.0
+    key = np.where(active, -e32.astype(np.float64), np.inf)
+    np.testing.assert_array_equal(perm, np.argsort(key, kind="stable"))
+
+
 # --- cyclic redistribution schedule invariants ----------------------------------
 
 

@@ -139,7 +139,6 @@ _ROUND_CACHE: dict = {}
 def _run_round(state, n_dev):
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.distributed import _shard_map
     from repro.core.redistribution import redistribute
 
     fn = _ROUND_CACHE.get(n_dev)
@@ -159,7 +158,11 @@ def _run_round(state, n_dev):
             )
             return jax.tree.map(lambda x: x[None], state)
 
-        fn = jax.jit(_shard_map(body, mesh=mesh, in_specs=P("dev"), out_specs=P("dev")))
+        fn = jax.jit(
+            jax.shard_map(
+                body, mesh=mesh, in_specs=P("dev"), out_specs=P("dev"), check_vma=False
+            )
+        )
         _ROUND_CACHE[n_dev] = fn
     return fn(state)
 
