@@ -43,54 +43,39 @@ import contextlib
 import json
 import os
 import sys
-import threading
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
-
-
-class _Compiles:
-    """Counts backend compiles (cache loads included) and cache hits.
-
-    The drivers compile the next window rungs in background threads, so
-    compile seconds are summed over threads and can exceed a phase's wall
-    time."""
-
-    def __init__(self) -> None:
-        self.count = 0
-        self.seconds = 0.0
-        self.cache_hits = 0
-        self._lock = threading.Lock()
-
-    def on_duration(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                self.count += 1
-                self.seconds += duration
-
-    def on_event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self.cache_hits += 1
 
 
 def _fail(phase: str, msg: str) -> None:
     raise SystemExit(f"{phase} FAILED: {msg}")
 
 
+def _builds(compiles) -> tuple:
+    """(programs built, their build seconds, cache loads) so far, from the
+    ``jax.*`` counters of the recorder that ``jax_events`` feeds.  The
+    drivers compile the next window rungs in background threads, so build
+    seconds are summed over threads and can exceed a phase's wall time."""
+    c = compiles.counters
+    return (c.get("jax.builds", 0), c.get("jax.build_s", 0.0),
+            c.get("jax.cache_loads", 0))
+
+
 @contextlib.contextmanager
-def _phase(name: str, compiles: _Compiles, devices):
+def _phase(name: str, compiles, devices):
     """Print wall time, compiles, cache hits and peak device bytes of a phase."""
-    c0, s0, h0 = compiles.count, compiles.seconds, compiles.cache_hits
+    c0, s0, h0 = _builds(compiles)
     t0 = time.perf_counter()
     info: dict = {}
     yield info
     wall = time.perf_counter() - t0
     peaks = [d.memory_stats()["peak_bytes_in_use"] for d in devices]
     fields = " ".join(f"{k}={v}" for k, v in info.items())
+    c1, s1, h1 = _builds(compiles)
     print(
-        f"{name}: {fields} wall_s={wall} compiles={compiles.count - c0} "
-        f"compile_s={compiles.seconds - s0} cache_hits={compiles.cache_hits - h0} "
+        f"{name}: {fields} wall_s={wall} compiles={c1 - c0} "
+        f"compile_s={s1 - s0} cache_hits={h1 - h0} "
         f"peak_bytes_in_use={peaks}",
         flush=True,
     )
@@ -316,19 +301,20 @@ def main() -> None:
     cache_dir = compile_cache.enable()
     print(f"compile cache: dir={cache_dir} "
           f"enabled={bool(jax.config.jax_enable_compilation_cache)}", flush=True)
-    compiles = _Compiles()
-    jax.monitoring.register_event_duration_secs_listener(compiles.on_duration)
-    jax.monitoring.register_event_listener(compiles.on_event)
+    from repro.telemetry import Recorder, jax_events
 
-    if args.four_chips:
-        four_chips(compiles, devices, args.seed)
-    else:
-        phase_integral(compiles, devices)
-        phase_service(compiles, devices, args.seed)
-        phase_vegas(compiles, devices, args.seed)
-        phase_kernel(compiles, devices, args.seed)
-    print(f"total: compiles={compiles.count} compile_s={compiles.seconds} "
-          f"cache_hits={compiles.cache_hits}", flush=True)
+    compiles = Recorder()
+    with jax_events(compiles):
+        if args.four_chips:
+            four_chips(compiles, devices, args.seed)
+        else:
+            phase_integral(compiles, devices)
+            phase_service(compiles, devices, args.seed)
+            phase_vegas(compiles, devices, args.seed)
+            phase_kernel(compiles, devices, args.seed)
+    count, seconds, hits = _builds(compiles)
+    print(f"total: compiles={count} compile_s={seconds} cache_hits={hits}",
+          flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": len(visible)}}))
 

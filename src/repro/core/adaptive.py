@@ -37,7 +37,7 @@ from repro.core.programs import RungPrograms
 from repro.core.region_store import RegionState
 from repro.core.rules import make_rule
 from repro.core.split import classify_split_compact
-from repro.telemetry import NULL
+from repro.telemetry import NULL, jax_events
 
 
 @dataclasses.dataclass
@@ -67,22 +67,25 @@ def make_eval_step(
     every active — hence every fresh — region lives in ``[0, n_active)``, so
     any ``window >= n_active`` produces bit-identical results to the legacy
     full-capacity evaluation while doing ``window / capacity`` of the work.
-    ``None`` evaluates the full store.
+    ``None`` evaluates the full store.  Its operations carry the named
+    scope ``quad.eval`` in every program that fuses it.
     """
 
     def eval_step(state: RegionState) -> RegionState:
-        w = state.capacity if window is None else min(window, state.capacity)
-        need = state.active[:w] & state.fresh[:w]
-        est, err, axis = rule.eval_batch(state.centers[:w], state.halfw[:w])
-        return dataclasses.replace(
-            state,
-            est=state.est.at[:w].set(jnp.where(need, est, state.est[:w])),
-            err=state.err.at[:w].set(jnp.where(need, err, state.err[:w])),
-            axis=state.axis.at[:w].set(jnp.where(need, axis, state.axis[:w])),
-            fresh=jnp.zeros_like(state.fresh),
-            n_evals=state.n_evals
-            + jnp.sum(need).astype(state.n_evals.dtype) * rule.n_evals_per_region,
-        )
+        with jax.named_scope("quad.eval"):
+            w = state.capacity if window is None else min(window, state.capacity)
+            need = state.active[:w] & state.fresh[:w]
+            est, err, axis = rule.eval_batch(state.centers[:w], state.halfw[:w])
+            return dataclasses.replace(
+                state,
+                est=state.est.at[:w].set(jnp.where(need, est, state.est[:w])),
+                err=state.err.at[:w].set(jnp.where(need, err, state.err[:w])),
+                axis=state.axis.at[:w].set(jnp.where(need, axis, state.axis[:w])),
+                fresh=jnp.zeros_like(state.fresh),
+                n_evals=state.n_evals
+                + jnp.sum(need).astype(state.n_evals.dtype)
+                * rule.n_evals_per_region,
+            )
 
     return eval_step
 
@@ -176,28 +179,30 @@ def make_advance_step(
     ``window`` rows only.  Exact (bit-identical to the full advance) whenever
     ``window >= advance_target(n_active, capacity)``; the drivers guarantee
     this by picking the rung from :func:`advance_ladder` for the active count
-    they already track.
+    they already track.  Its operations carry the named scope
+    ``quad.advance``.
     """
     width = jnp.asarray(domain_width)
     w = None if window is None else min(int(window), cfg.capacity)
 
     def advance(state: RegionState, budget=None, rel_tol=None) -> RegionState:
-        sl = slice(None) if w is None else slice(0, w)
-        integral, _ = state.global_estimates(window=w)
-        fin = classify(
-            cfg,
-            state.est[sl],
-            state.err[sl],
-            state.halfw[sl],
-            state.active[sl],
-            integral,
-            total_volume,
-            width,
-            budget=budget,
-            rel_tol=rel_tol,
-        )
-        state = classify_split_compact(state, fin, window=w)
-        return dataclasses.replace(state, it=state.it + 1)
+        with jax.named_scope("quad.advance"):
+            sl = slice(None) if w is None else slice(0, w)
+            integral, _ = state.global_estimates(window=w)
+            fin = classify(
+                cfg,
+                state.est[sl],
+                state.err[sl],
+                state.halfw[sl],
+                state.active[sl],
+                integral,
+                total_volume,
+                width,
+                budget=budget,
+                rel_tol=rel_tol,
+            )
+            state = classify_split_compact(state, fin, window=w)
+            return dataclasses.replace(state, it=state.it + 1)
 
     return advance
 
@@ -326,9 +331,21 @@ def integrate(
     ``recorder`` (a :class:`repro.telemetry.Recorder`) gets per-iteration
     ``core.eval``/``core.advance`` spans and a ``core.iter`` instant with
     the synced estimates — all recorded host-side between dispatches, so
-    telemetry cannot change the refinement trajectory.
+    telemetry cannot change the refinement trajectory.  Inside those spans
+    each host activity has a span of its own: ``core.program`` (getting a
+    rung's jitted program, waiting on its ahead compile), ``core.dispatch``
+    (calling it: a fresh program traces, lowers and loads here) and
+    ``core.sync`` (the blocking read-back); ``core.setup`` and
+    ``core.finish`` bracket the loop.  JAX's build events are counted
+    throughout (:func:`repro.telemetry.jax_events`).
     """
-    cfg, lo, hi, total_volume, rule, state = _setup(cfg, integrand)
+    with jax_events(recorder):
+        return _integrate(cfg, integrand, callback, recorder)
+
+
+def _integrate(cfg, integrand, callback, recorder) -> AdaptiveResult:
+    with recorder.span("core.setup"):
+        cfg, lo, hi, total_volume, rule, state = _setup(cfg, integrand)
 
     ladder = eval_ladder(cfg)
     adv_ladder = advance_ladder(cfg)
@@ -367,9 +384,9 @@ def integrate(
 
         return jax.jit(advance_and_count, donate_argnums=0)
 
-    evals = RungPrograms(ladder, build_eval)
-    metrics = RungPrograms(adv_ladder, build_metrics)
-    advances = RungPrograms(adv_ladder, build_advance)
+    evals = RungPrograms(ladder, build_eval, recorder)
+    metrics = RungPrograms(adv_ladder, build_metrics, recorder)
+    advances = RungPrograms(adv_ladder, build_advance, recorder)
 
     def eval_step_for(n_active: int, state) -> Callable:
         return evals(region_store.select_window(ladder, n_active), state)
@@ -387,10 +404,16 @@ def integrate(
     n_active = n_next = cfg.resolved_n_init()
     for _ in range(cfg.max_iters):
         with recorder.span("core.eval", window=int(n_next)):
-            state = eval_step_for(n_next, state)(state)
-            integral, error, n_active = (
-                float(x) for x in metrics_for(n_next, state)(state)
-            )
+            with recorder.span("core.program"):
+                eval_fn = eval_step_for(n_next, state)
+            with recorder.span("core.dispatch"):
+                state = eval_fn(state)
+            with recorder.span("core.program"):
+                metrics_fn = metrics_for(n_next, state)
+            with recorder.span("core.dispatch"):
+                out = metrics_fn(state)
+            with recorder.span("core.sync"):
+                integral, error, n_active = (float(x) for x in out)
         if recorder.enabled:
             recorder.event(
                 "core.iter",
@@ -416,27 +439,32 @@ def integrate(
         if n_active == 0:
             break
         with recorder.span("core.advance", n_active=int(n_active)):
-            state, n_dev = advance_for(int(n_active), state)(state)
-            n_next = int(n_dev)
-    for programs in (evals, metrics, advances):
-        programs.close()
+            with recorder.span("core.program"):
+                advance_fn = advance_for(int(n_active), state)
+            with recorder.span("core.dispatch"):
+                state, n_dev = advance_fn(state)
+            with recorder.span("core.sync"):
+                n_next = int(n_dev)
 
-    return AdaptiveResult(
-        integral=integral,
-        error=error,
-        status=result_status(
-            converged,
-            int(n_active),
-            int(state.it),
-            cfg,
-            bool(state.overflowed),
-            nonfinite,
-        ),
-        iterations=int(state.it),
-        n_evals=float(state.n_evals),
-        n_active=int(n_active),
-        overflowed=bool(state.overflowed),
-    )
+    with recorder.span("core.finish"):
+        for programs in (evals, metrics, advances):
+            programs.close()
+        return AdaptiveResult(
+            integral=integral,
+            error=error,
+            status=result_status(
+                converged,
+                int(n_active),
+                int(state.it),
+                cfg,
+                bool(state.overflowed),
+                nonfinite,
+            ),
+            iterations=int(state.it),
+            n_evals=float(state.n_evals),
+            n_active=int(n_active),
+            overflowed=bool(state.overflowed),
+        )
 
 
 def integrate_device(

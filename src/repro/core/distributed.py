@@ -47,6 +47,7 @@ from repro.core.redistribution import balance_stats, make_schedule, redistribute
 from repro.core.region_store import RegionState
 from repro.core.rules import make_rule
 from repro.core.split import classify_split_compact
+from repro.telemetry import NULL, jax_events
 
 AXIS = "dev"
 
@@ -133,24 +134,26 @@ def make_classify_split(
     Unlike :func:`repro.core.adaptive.make_advance_step` this takes the
     *psum'd* integral and global active count (every device classifies
     against the same equal-share threshold) and does NOT bump ``it`` — the
-    redistribution schedule indexes on the pre-bump counter.
+    redistribution schedule indexes on the pre-bump counter.  Its
+    operations carry the named scope ``quad.advance``.
     """
     width = jnp.asarray(domain_width)
     sl = slice(None) if window is None else slice(0, window)
 
     def apply(state: RegionState, integral, n_global) -> RegionState:
-        fin = classify(
-            cfg,
-            state.est[sl],
-            state.err[sl],
-            state.halfw[sl],
-            state.active[sl],
-            integral,
-            total_volume,
-            width,
-            n_active=n_global,
-        )
-        return classify_split_compact(state, fin, window=window)
+        with jax.named_scope("quad.advance"):
+            fin = classify(
+                cfg,
+                state.est[sl],
+                state.err[sl],
+                state.halfw[sl],
+                state.active[sl],
+                integral,
+                total_volume,
+                width,
+                n_active=n_global,
+            )
+            return classify_split_compact(state, fin, window=window)
 
     return apply
 
@@ -202,18 +205,19 @@ def make_dist_step(
 
         # --- metadata exchange (the only global sync point) ----------------
         i_loc, e_loc = state.global_estimates(window=eval_w)
-        integral = jax.lax.psum(i_loc, AXIS)
-        error = jax.lax.psum(e_loc, AXIS)
-        n_loc = jnp.sum(state.active).astype(jnp.int32)
-        n_global = jax.lax.psum(n_loc, AXIS)
-        work_max = jax.lax.pmax(work_loc, AXIS)
-        work_sum = jax.lax.psum(work_loc, AXIS)
-        work_imb = jnp.where(
-            work_max > 0,
-            1.0 - (work_sum / n_devices) / jnp.maximum(work_max, 1),
-            0.0,
-        )
-        max_rows, _, _ = balance_stats(n_loc, AXIS, n_devices)
+        with jax.named_scope("quad.estimates"):
+            integral = jax.lax.psum(i_loc, AXIS)
+            error = jax.lax.psum(e_loc, AXIS)
+            n_loc = jnp.sum(state.active).astype(jnp.int32)
+            n_global = jax.lax.psum(n_loc, AXIS)
+            work_max = jax.lax.pmax(work_loc, AXIS)
+            work_sum = jax.lax.psum(work_loc, AXIS)
+            work_imb = jnp.where(
+                work_max > 0,
+                1.0 - (work_sum / n_devices) / jnp.maximum(work_max, 1),
+                0.0,
+            )
+            max_rows, _, _ = balance_stats(n_loc, AXIS, n_devices)
 
         # --- classify + split (global equal-share threshold) ---------------
         state = classify_split(state, integral, n_global)
@@ -326,7 +330,7 @@ def integrate_distributed(
     integrand: Optional[Callable] = None,
     mesh: Optional[Mesh] = None,
     devices=None,
-    recorder=None,
+    recorder=NULL,
 ) -> DistributedResult:
     """Host-driven multi-device integration over ``devices`` (default: every
     visible device; ``mesh`` overrides both).
@@ -335,34 +339,43 @@ def integrate_distributed(
     ``dist.dispatch`` span per fused launch and, per executed iteration, a
     ``dist.work_imb`` gauge (the paper's Fig. 4b idle-time proxy, the same
     value appended to ``history``) plus a ``dist.iter`` instant — recorded
-    from the read-back metrics only, after the dispatch returns.
+    from the read-back metrics only, after the dispatch returns.  Inside
+    each ``dist.dispatch``, ``dist.program`` gets the rung's jitted program
+    (waiting on its ahead compile) and ``dist.sync`` is the blocking
+    read-back; ``dist.setup`` covers the mesh, the rule and the initial
+    state.  JAX's build events are counted throughout
+    (:func:`repro.telemetry.jax_events`).
     """
-    cfg = cfg.validate()
-    if mesh is None:
-        devices = devices if devices is not None else jax.devices()
-        mesh = jax.make_mesh((len(devices),), (AXIS,), devices=devices)
-    n_devices = mesh.shape[AXIS]
+    with jax_events(recorder):
+        return _integrate_distributed(cfg, integrand, mesh, devices, recorder)
 
-    lo = np.asarray(cfg.lo(), np.float64)
-    hi = np.asarray(cfg.hi(), np.float64)
-    total_volume = float(np.prod(hi - lo))
-    dtype = jnp.dtype(cfg.dtype)
-    rule = make_rule(cfg, integrand, platform=mesh.devices.flat[0].platform)
 
-    state = _stacked_initial_state(cfg, n_devices, dtype)
-    n_widest = int(jnp.max(jnp.sum(state.active, axis=1)))
-    shard = NamedSharding(mesh, P(AXIS))
-    state = jax.device_put(state, shard)
+def _integrate_distributed(cfg, integrand, mesh, devices, recorder):
+    with recorder.span("dist.setup"):
+        cfg = cfg.validate()
+        if mesh is None:
+            devices = devices if devices is not None else jax.devices()
+            mesh = jax.make_mesh((len(devices),), (AXIS,), devices=devices)
+        n_devices = mesh.shape[AXIS]
+
+        lo = np.asarray(cfg.lo(), np.float64)
+        hi = np.asarray(cfg.hi(), np.float64)
+        total_volume = float(np.prod(hi - lo))
+        dtype = jnp.dtype(cfg.dtype)
+        rule = make_rule(cfg, integrand, platform=mesh.devices.flat[0].platform)
+
+        state = _stacked_initial_state(cfg, n_devices, dtype)
+        n_widest = int(jnp.max(jnp.sum(state.active, axis=1)))
+        shard = NamedSharding(mesh, P(AXIS))
+        state = jax.device_put(state, shard)
 
     ladder = eval_ladder(cfg)
     steps = RungPrograms(
         ladder,
         lambda w: dist_program(cfg, rule, mesh, total_volume, hi - lo, w),
+        recorder,
     )
 
-    from repro.telemetry import NULL
-
-    recorder = NULL if recorder is None else recorder
     history = []
     converged = False
     integral = error = 0.0
@@ -371,10 +384,13 @@ def integrate_distributed(
     while it < cfg.max_iters:
         with recorder.span("dist.dispatch", it=it) as sp:
             w = region_store.select_window(ladder, n_widest)
-            state, ms, executed, n_widest = steps(w, state)(state)
-            executed = np.asarray(executed)
-            n_widest = int(n_widest)
-            ms = jax.device_get(ms)
+            with recorder.span("dist.program", window=w):
+                step = steps(w, state)
+            state, ms, executed, n_widest = step(state)
+            with recorder.span("dist.sync"):
+                executed = np.asarray(executed)
+                n_widest = int(n_widest)
+                ms = jax.device_get(ms)
             sp["executed"] = int(np.sum(executed))
         for t in range(len(executed)):
             if not executed[t]:

@@ -8,6 +8,10 @@ the current rung runs, the next :data:`AHEAD` rungs compile in background
 threads (an XLA compile releases the GIL), and a cold run waits for about
 one compile in ``AHEAD + 1`` instead of one per rung.  A jitted function
 that was lowered and compiled ahead does not compile again when called.
+
+A recorder counts ``core.programs.ahead``, the compiles started ahead, and
+at :meth:`RungPrograms.close` ``core.programs.ahead_unused``, the rungs
+compiled or started ahead that the run never called.
 """
 
 from __future__ import annotations
@@ -17,6 +21,8 @@ from typing import Callable, Optional
 
 import jax
 from jax.sharding import NamedSharding
+
+from repro.telemetry import NULL
 
 #: rungs compiled ahead of the one in use
 AHEAD = 3
@@ -39,11 +45,13 @@ class RungPrograms:
     built by ``build(rung)`` on first use, and starts compiling the next
     rungs of ``ladder`` for arguments shaped like ``args``."""
 
-    def __init__(self, ladder, build: Callable[[int], Callable]):
+    def __init__(self, ladder, build: Callable[[int], Callable], recorder=NULL):
         self.ladder = tuple(ladder)
         self._build = build
+        self._recorder = recorder
         self._fns: dict[int, Callable] = {}
         self._ahead: dict[int, Future] = {}
+        self._called: set[int] = set()
         self._pool: Optional[ThreadPoolExecutor] = None
 
     def _fn(self, rung: int) -> Callable:
@@ -54,6 +62,7 @@ class RungPrograms:
 
     def __call__(self, rung: int, *args) -> Callable:
         fn = self._fn(rung)
+        self._called.add(rung)
         pending = self._ahead.get(rung)
         if pending is not None:
             pending.result()  # calling fn before this ends compiles it twice
@@ -67,9 +76,16 @@ class RungPrograms:
                 self._ahead[w] = self._pool.submit(
                     lambda f=self._fn(w): f.lower(*specs).compile()
                 )
+            self._recorder.count("core.programs.ahead", len(nxt))
         return fn
 
     def close(self) -> None:
         """Drop compiles not yet started (one under way runs to its end)."""
         if self._pool is not None:
             self._pool.shutdown(wait=False, cancel_futures=True)
+        unused = [
+            w
+            for w, f in self._ahead.items()
+            if w not in self._called and not f.cancelled()
+        ]
+        self._recorder.count("core.programs.ahead_unused", len(unused))

@@ -151,10 +151,22 @@ def redistribute(
     cap: int,
     limit: int,
 ) -> RegionState:
-    """One redistribution round (inside shard_map). See module docstring."""
+    """One redistribution round (inside shard_map). See module docstring.
+    Its operations carry the named scope ``quad.redistribute``."""
     if n_devices <= 1 or not schedule:
         return state
+    with jax.named_scope("quad.redistribute"):
+        return _redistribute(state, axis_name, n_devices, schedule, cap, limit)
 
+
+def _redistribute(
+    state: RegionState,
+    axis_name: str,
+    n_devices: int,
+    schedule: Sequence[int],
+    cap: int,
+    limit: int,
+) -> RegionState:
     C = state.capacity
     d = state.d
     idx = jnp.arange(C)

@@ -90,13 +90,15 @@ class RegionState:
         active slot sits inside the window, which the active-window invariant
         guarantees for any ``window >= n_active`` (the masked tail contributes
         exact zeros, so the windowed and full reductions agree bitwise).
+        Its operations carry the named scope ``quad.estimates``.
         """
-        act = self.active if window is None else self.active[:window]
-        est = self.est if window is None else self.est[:window]
-        err = self.err if window is None else self.err[:window]
-        integral = self.fin_integral + jnp.sum(jnp.where(act, est, 0.0))
-        error = self.fin_error + jnp.sum(jnp.where(act, err, 0.0))
-        return integral, error
+        with jax.named_scope("quad.estimates"):
+            act = self.active if window is None else self.active[:window]
+            est = self.est if window is None else self.est[:window]
+            err = self.err if window is None else self.err[:window]
+            integral = self.fin_integral + jnp.sum(jnp.where(act, est, 0.0))
+            error = self.fin_error + jnp.sum(jnp.where(act, err, 0.0))
+            return integral, error
 
 
 def empty_state(capacity: int, d: int, dtype) -> RegionState:

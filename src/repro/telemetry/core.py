@@ -1,14 +1,16 @@
 """Recorder: the host-side event spine of the telemetry subsystem.
 
-Everything here is plain Python over plain dicts — no third-party
-dependencies, no background threads, no global mutable registry.  A
-:class:`Recorder` turns instrumentation calls (``count`` / ``gauge`` /
-``observe`` / ``event`` / ``flow`` / ``span``) into *event dicts* pushed to
-attached sinks (see :mod:`repro.telemetry.sinks`), while keeping cheap
-in-memory aggregates for the end-of-run summary table.
+Everything here is plain Python over plain dicts — no third-party import
+at load (JAX's profiler is imported on the first span), no background
+threads, no global mutable registry.  A :class:`Recorder` turns
+instrumentation calls (``count`` / ``gauge`` / ``observe`` / ``event`` /
+``flow`` / ``span``) into *event dicts* pushed to attached sinks (see
+:mod:`repro.telemetry.sinks`), while keeping cheap in-memory aggregates for
+the end-of-run summary table.
 
 The cardinal rule (DESIGN.md §8): **nothing is recorded inside traced
-code.**  Instrumented call sites live strictly at dispatch boundaries —
+code; named scopes, which change only metadata, are allowed.**
+Instrumented call sites live strictly at dispatch boundaries —
 after ``jax.device_get`` of a fused step's metrics, around ``engine.run``,
 inside the host-side admission/collection loops.  The recorder therefore
 never perturbs a jitted program: with telemetry on or off the compiled
@@ -33,13 +35,35 @@ Event schema (one dict per event; sinks serialize it verbatim)::
 
 The clock is injectable (``Recorder(clock=fake)``) so tests assert exact
 span durations and orderings deterministically.
+
+Counters and the event stream take a lock: JAX's build events are counted
+from the threads that compile rungs ahead (:mod:`repro.telemetry.monitoring`)
+while the driving thread records its spans.  Spans, gauges and histograms
+belong to one thread.
+
+Spans also land in the profiler's own trace: an enabled recorder opens a
+``jax.profiler.TraceAnnotation`` named like the span on the calling thread
+for the span's duration, so a ``jax.profiler`` trace shows the host's spans
+on the device trace's clock, beside the device's operations.  The
+annotation factory is injectable too (``Recorder(annotate=fake)``); the
+event stream keeps the recorder clock.
 """
 
 from __future__ import annotations
 
+import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import (
+    Any,
+    Callable,
+    ContextManager,
+    Dict,
+    Iterator,
+    List,
+    Optional,
+    Tuple,
+)
 
 
 #: Per-histogram raw-sample retention cap.  ``observe`` keeps the first N
@@ -71,6 +95,15 @@ def quantile(values: List[float], q: float) -> float:
     return s[lo] * (1.0 - frac) + s[hi] * frac
 
 
+def trace_annotation(name: str) -> ContextManager:
+    """``jax.profiler.TraceAnnotation(name)``: a host span in the profiler's
+    trace (a no-op while no profiler session runs).  JAX is imported on the
+    first span, not with this module."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
+
+
 class Recorder:
     """Collects structured telemetry events and aggregates.
 
@@ -83,6 +116,10 @@ class Recorder:
     clock:
         Zero-arg callable returning seconds.  Defaults to
         :func:`time.monotonic`; inject a fake for deterministic tests.
+    annotate:
+        ``annotate(name)`` returns the context manager each span opens on
+        the calling thread for its duration.  Defaults to
+        :func:`trace_annotation`; inject a fake for tests.
     """
 
     enabled = True
@@ -91,9 +128,12 @@ class Recorder:
         self,
         sinks: Tuple[Any, ...] = (),
         clock: Callable[[], float] = time.monotonic,
+        annotate: Callable[[str], ContextManager] = trace_annotation,
     ) -> None:
         self.sinks: List[Any] = list(sinks)
         self.clock = clock
+        self.annotate = annotate
+        self._lock = threading.Lock()
         self._seq = 0
         self._flow_id = 0
         self._span_depth = 0
@@ -110,10 +150,11 @@ class Recorder:
         self.sinks.append(sink)
 
     def _emit(self, event: Dict[str, Any]) -> None:
-        event["seq"] = self._seq
-        self._seq += 1
-        for sink in self.sinks:
-            sink.emit(event)
+        with self._lock:
+            event["seq"] = self._seq
+            self._seq += 1
+            for sink in self.sinks:
+                sink.emit(event)
 
     def flush(self) -> None:
         for sink in self.sinks:
@@ -137,8 +178,9 @@ class Recorder:
         The event carries the running ``total`` so trace export can draw a
         cumulative counter track without replaying the stream.
         """
-        total = self.counters.get(name, 0) + n
-        self.counters[name] = total
+        with self._lock:
+            total = self.counters.get(name, 0) + n
+            self.counters[name] = total
         # attrs first throughout: a caller attr must never overwrite the
         # envelope ("kind", "ts", ...) — a collision would silently turn the
         # event into an unknown type that every consumer drops
@@ -210,12 +252,6 @@ class Recorder:
         """
         return quantile(self.hist_samples.get(name, []), q)
 
-    def hist_quantiles(
-        self, name: str, qs: Tuple[float, ...] = (0.5, 0.99)
-    ) -> Dict[float, float]:
-        """Several quantiles of histogram ``name`` at once (p50/p99 default)."""
-        return {q: self.quantile(name, q) for q in qs}
-
     def event(
         self, name: str, lane: Optional[int] = None, **attrs: Any
     ) -> None:
@@ -240,8 +276,15 @@ class Recorder:
 
         Yields a mutable attrs dict — entries added inside the body ride on
         the ``span_end`` event (e.g. ``sp["executed"] = k`` after a fused
-        dispatch returns how many iterations actually ran).
+        dispatch returns how many iterations actually ran).  The body also
+        runs inside ``annotate(name)``: the span in the profiler's trace.
         """
+        with self.annotate(name):
+            yield from self._span(name, lane, attrs)
+
+    def _span(
+        self, name: str, lane: Optional[int], attrs: Dict[str, Any]
+    ) -> Iterator[Dict[str, Any]]:
         t0 = self.clock()
         depth = self._span_depth
         self._span_depth = depth + 1
@@ -370,11 +413,6 @@ class NullRecorder:
 
     def quantile(self, name: str, q: float) -> float:
         return 0.0
-
-    def hist_quantiles(
-        self, name: str, qs: Tuple[float, ...] = (0.5, 0.99)
-    ) -> Dict[float, float]:
-        return {q: 0.0 for q in qs}
 
     def event(self, name: str, lane: Optional[int] = None, **attrs: Any) -> None:
         return None
